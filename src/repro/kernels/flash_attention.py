@@ -14,12 +14,14 @@ which lowers the same algorithm for the CPU dry-run).
 from __future__ import annotations
 
 import functools
-import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import interpret_mode
 
 NEG_INF = -1e30
 
@@ -78,11 +80,14 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            window: int = 0, softcap: float = 0.0,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True):
+                           interpret: Optional[bool] = None):
     """q: (B, H, Sq, D); k, v: (B, KVH, Sk, D).  Returns (B, H, Sq, D).
 
     ``window`` <= 0 means full attention.  Sq/Sk are padded to block
-    multiples internally; padded keys are masked via ``k_len``."""
+    multiples internally; padded keys are masked via ``k_len``.
+    ``interpret=None`` follows the backend (``interpret_mode``)."""
+    if interpret is None:
+        interpret = interpret_mode()
     B, H, Sq, D = q.shape
     KVH, Sk = k.shape[1], k.shape[2]
     G = H // KVH

@@ -1,23 +1,18 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults from REPRO_PALLAS_INTERPRET (=1 on this CPU
-container; set 0 on real TPUs).  The wrappers adapt the model-side
-(B, S, H, D) layout to the kernels' (B, H, S, D) TPU-friendly layout.
+The kernels run compiled on a TPU and in the Pallas interpreter on any
+other backend (``repro.kernels.platform``).  The wrappers adapt the
+model-side (B, S, H, D) layout to the kernels' (B, H, S, D) TPU-friendly
+layout.
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.ssd_scan import ssd_scan as _ssd_scan
-
-
-def _interpret_default() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "softcap"))
@@ -28,12 +23,11 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
     out = flash_attention_pallas(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
         v.transpose(0, 2, 1, 3), causal=causal, window=win,
-        softcap=softcap, interpret=_interpret_default())
+        softcap=softcap)
     return out.transpose(0, 2, 1, 3)
 
 
 @partial(jax.jit, static_argnames=("chunk",))
 def ssd(x, B, C, dt, A, D, chunk: int = 256):
     """Mamba2 SSD over full sequences (see kernels/ssd_scan.py)."""
-    return _ssd_scan(x, B, C, dt, A, D, chunk,
-                     interpret=_interpret_default())
+    return _ssd_scan(x, B, C, dt, A, D, chunk)

@@ -34,7 +34,8 @@ from repro.launch import specs as lspecs
 from repro.models import decode_step, prefill
 from repro.models.moe import resolve_groups
 from repro.train.optimizer import for_model, opt_state_specs
-from repro.train.train_step import make_train_step, resolve_microbatches
+from repro.train.train_step import (
+    ACT_BUDGET, HBM_BYTES, make_train_step, resolve_microbatches)
 
 
 def _resolve_moe(cfg: ModelConfig, shape: ShapeConfig, mesh) -> ModelConfig:
@@ -64,7 +65,8 @@ def build_cell(cfg: ModelConfig, shape: ShapeConfig, mesh,
         bshard = shd.to_shardings(mesh, shd.batch_specs(cfg, shape, mesh))
         k = microbatches or resolve_microbatches(
             cfg, shape.global_batch, shape.seq_len,
-            shd.axis_size(mesh, shd.batch_axes(mesh)))
+            shd.axis_size(mesh, shd.batch_axes(mesh)),
+            budget_bytes=int(HBM_BYTES * ACT_BUDGET))
         step = make_train_step(cfg, ocfg, microbatches=k)
         jitted = jax.jit(step,
                          in_shardings=(pshard, oshard, bshard),

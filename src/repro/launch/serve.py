@@ -26,9 +26,11 @@ def main() -> None:
     import numpy as np
 
     from repro.configs import get_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import init_params
     from repro.serve.engine import Request, ServeEngine
 
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     params = init_params(cfg, jax.random.PRNGKey(0))
     if args.checkpoint:

@@ -136,10 +136,13 @@ def ssd_chunked(xh, Bmat, Cmat, dt, A, D, chunk: int,
 
     # ---- intra-chunk (quadratic within chunk) ------------------------------
     CB = jnp.einsum("bcln,bcmn->bclm", Cc, Bc)          # (B,nc,L,L)
-    # decay[b,c,h,i,j] = exp(cum_i - cum_j), lower triangular
-    decay = jnp.exp(cum[..., :, None, :] - cum[..., None, :, :])  # (B,nc,L,L,nh)
-    tri = jnp.tril(jnp.ones((L, L), jnp.float32))
-    M = CB[..., None] * decay * tri[None, None, :, :, None]       # (B,nc,L,L,nh)
+    # decay[b,c,i,j,h] = exp(cum_i - cum_j) for j <= i, else 0.  Mask the
+    # exponent, not the product: above the diagonal cum_i - cum_j > 0 grows
+    # with the chunk, exp overflows to inf, and inf * 0 is NaN.
+    tri = jnp.tril(jnp.ones((L, L), bool))[None, None, :, :, None]
+    seg = cum[..., :, None, :] - cum[..., None, :, :]             # (B,nc,L,L,nh)
+    decay = jnp.exp(jnp.where(tri, seg, -jnp.inf))
+    M = CB[..., None] * decay                                     # (B,nc,L,L,nh)
     M = (M * dtc[:, :, None, :, :]).astype(intra_dtype)  # weight by dt_j
     y_intra = jnp.einsum("bcijh,bcjhp->bcihp", M, xf,
                          preferred_element_type=intra_dtype)

@@ -87,16 +87,28 @@ def make_eval_step(cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 # microbatch auto-resolution (used by the dry-run and the trainer)
 
-HBM_BYTES = 16 * 2**30          # TPU v5e-class chip
+HBM_BYTES = 16 * 2**30          # TPU v5e: the dry-run's explicit target
 ACT_BUDGET = 0.45               # fraction of HBM available for activations
+
+
+def _device_act_budget() -> Optional[int]:
+    """Activation budget of this process's first device, from the HBM
+    limit it reports; None where it reports none (the CPU backend)."""
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    return int(limit * ACT_BUDGET) if limit else None
 
 
 def resolve_microbatches(cfg: ModelConfig, global_batch: int, seq: int,
                          data_shards: int, budget_bytes: int = None) -> int:
     """Smallest power-of-two k such that the per-device layer-carry stacks
     (the dominant remat residual: ~6 bytes/elem — bf16 saved carry plus the
-    f32 copy XLA materializes on this backend) fit the activation budget."""
-    budget = budget_bytes or int(HBM_BYTES * ACT_BUDGET)
+    f32 copy XLA materializes on this backend) fit the activation budget:
+    ``budget_bytes``, else the device's (``_device_act_budget``).  With no
+    budget at all there is nothing to fit and k is 1."""
+    budget = budget_bytes or _device_act_budget()
+    if budget is None:
+        return 1
     per_dev_batch = max(global_batch // data_shards, 1)
     d_eff = cfg.d_model
     if cfg.family in ("ssm", "hybrid"):

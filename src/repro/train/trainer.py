@@ -76,6 +76,7 @@ class Trainer:
                             microbatches=tcfg.microbatches),
             donate_argnums=(0, 1))
         self.metrics_log: list = []
+        self.final_state = None            # (params, opt_state) after run()
         # Profiling goes through the repro.profiler façade: pass a
         # Profiler (or ProfilerOptions) with a step_window, or use the
         # legacy TrainerConfig.profile_first/last fields, which build an
@@ -190,5 +191,5 @@ class Trainer:
                     self.ckpt.wait()     # drain any in-flight async save
                     self.ckpt.save(step, tree, extra={"step": step})
         # keep final state reachable for callers/tests
-        self._final = (params, opt_state)
+        self.final_state = (params, opt_state)
         return step
