@@ -17,8 +17,10 @@ from __future__ import annotations
 
 import json
 import socket
+import sys
 import threading
 import time
+from contextlib import nullcontext
 from typing import Optional
 
 from repro.core.attach import attach as _attach, detach as _detach, is_attached as _is_attached
@@ -162,15 +164,31 @@ class StepCallback:
 
     def on_step_begin(self, step: int) -> None:
         if step == self.first:
-            self.session.start()
+            self._start()
         elif (self.every and self.first < step <= self.last
               and (step - self.first) % self.every == 0):
-            self.session.stop()
-            self.session.start()
+            self._stop()
+            self._start()
 
     def on_step_end(self, step: int) -> None:
         if step == self.last and self.session._active:
+            self._stop()
+
+    def _start(self) -> None:
+        with _trace_span("profiler.start"):
+            self.session.start()
+
+    def _stop(self) -> None:
+        with _trace_span("profiler.stop"):
             self.session.stop()
+
+
+def _trace_span(name: str):
+    """A ``jax.profiler.TraceAnnotation`` where JAX is loaded, so the
+    session's own stops and starts show on a training step's trace; a
+    process that never imported JAX is not tracing with it."""
+    jax = sys.modules.get("jax")
+    return jax.profiler.TraceAnnotation(name) if jax else nullcontext()
 
 
 class ProfileServer:

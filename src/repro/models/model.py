@@ -128,9 +128,10 @@ def forward(params, cfg: ModelConfig, batch: dict, *,
 def loss_fn(params, cfg: ModelConfig, batch: dict):
     tokens = batch["tokens"]
     hidden, aux, _ = forward(params, cfg, batch)
-    unembed = unembed_matrix(params["embedding"], cfg)
     labels = tokens[:, 1:]
-    per_token = cross_entropy(hidden[:, :-1, :], unembed, labels, cfg)
+    with jax.named_scope("loss"):
+        unembed = unembed_matrix(params["embedding"], cfg)
+        per_token = cross_entropy(hidden[:, :-1, :], unembed, labels, cfg)
     mask = batch.get("loss_mask")
     mask = jnp.ones_like(per_token) if mask is None else mask[:, 1:]
     ce = masked_mean(per_token, mask)

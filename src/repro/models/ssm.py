@@ -62,6 +62,7 @@ def _split_proj(cfg: ModelConfig, zxbcdt: jnp.ndarray):
     return z, xBC, dt_raw
 
 
+@jax.named_scope("conv")
 def causal_conv(xBC: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
                 state: Optional[jnp.ndarray] = None):
     """Depthwise causal conv over time.  xBC: (B, S, Ch), w: (W, Ch).
@@ -86,7 +87,8 @@ def _preprocess(p: dict, cfg: ModelConfig, x: jnp.ndarray,
     """Shared front half: in_proj + conv + head split + dt/A."""
     cdt = dtype_of(cfg.compute_dtype)
     d_inner, nh, P, N = dims(cfg)
-    zxbcdt = x.astype(cdt) @ p["in_proj"].astype(cdt)
+    with jax.named_scope("in_proj"):
+        zxbcdt = x.astype(cdt) @ p["in_proj"].astype(cdt)
     z, xBC, dt_raw = _split_proj(cfg, zxbcdt)
     xBC, new_conv = causal_conv(xBC, p["conv_w"].astype(cdt),
                                 p["conv_b"].astype(cdt), conv_state)
@@ -100,6 +102,7 @@ def _preprocess(p: dict, cfg: ModelConfig, x: jnp.ndarray,
     return z, xh, Bmat, Cmat, dt, A, new_conv
 
 
+@jax.named_scope("out_proj")
 def _finish(p: dict, cfg: ModelConfig, y_heads: jnp.ndarray, z: jnp.ndarray):
     cdt = dtype_of(cfg.compute_dtype)
     B_, S_ = z.shape[0], z.shape[1]
@@ -110,6 +113,7 @@ def _finish(p: dict, cfg: ModelConfig, y_heads: jnp.ndarray, z: jnp.ndarray):
     return y @ p["out_proj"].astype(cdt)
 
 
+@jax.named_scope("ssd")
 def ssd_chunked(xh, Bmat, Cmat, dt, A, D, chunk: int,
                 h_init: Optional[jnp.ndarray] = None,
                 intra_dtype=jnp.float32):

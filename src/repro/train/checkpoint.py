@@ -13,6 +13,11 @@
 * Keep-N GC + ``latest_step`` discovery for auto-resume after failure.
 * All writes go through buffered Python file objects, so a profiling
   session records them on the STDIO layer (paper §IV-D / Fig 6).
+* Traced:      ``ckpt.*`` spans (``jax.profiler.TraceAnnotation``) name
+               each phase on the profiler's timeline: on the caller's
+               thread ``ckpt.wait_writer`` and ``ckpt.snapshot``; per
+               save ``ckpt.write`` holding ``ckpt.serialize`` (per leaf),
+               ``ckpt.file_write``, ``ckpt.fsync`` and ``ckpt.commit``.
 """
 from __future__ import annotations
 
@@ -25,6 +30,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 MANIFEST = "MANIFEST.json"
 
@@ -42,9 +48,11 @@ def _tree_paths(tree) -> List[tuple]:
 def _write_atomic(path: str, data: bytes) -> None:
     tmp = path + ".tmp"
     with open(tmp, "wb") as f:
-        f.write(data)
-        f.flush()
-        os.fsync(f.fileno())
+        with TraceAnnotation("ckpt.file_write"):
+            f.write(data)
+            f.flush()
+        with TraceAnnotation("ckpt.fsync"):
+            os.fsync(f.fileno())
     os.rename(tmp, path)
 
 
@@ -56,8 +64,9 @@ class CheckpointManager:
         self._thread: Optional[threading.Thread] = None
         self._error: Optional[BaseException] = None
         # writer throttle (repro.tune throttle-checkpoint actions):
-        # async saves within min_interval_s of the previous save are
-        # skipped; sync saves always land (the final save must commit)
+        # async saves within min_interval_s of the previous save's call
+        # are skipped without waiting on the writer; sync saves always
+        # land (the final save must commit)
         self.min_interval_s = 0.0
         self.throttle_skipped = 0
         self._last_save_t: Optional[float] = None
@@ -73,39 +82,47 @@ class CheckpointManager:
     def save(self, step: int, tree: Dict[str, Any],
              extra: Optional[dict] = None) -> str:
         """Synchronous atomic save.  ``tree`` is a pytree of arrays."""
-        ckpt_dir = os.path.join(self.directory, f"step_{step:010d}")
-        stage = ckpt_dir + ".staging"
-        os.makedirs(stage, exist_ok=True)
-        entries = []
-        for name, leaf in _tree_paths(tree):
-            arr = np.asarray(jax.device_get(leaf))
-            fname = name.replace("/", ".") + ".npy"
-            payload = _npy_bytes(arr)
-            _write_atomic(os.path.join(stage, fname), payload)
-            entries.append({"name": name, "file": fname,
-                            "shape": list(arr.shape), "dtype": str(arr.dtype),
-                            "crc32": zlib.crc32(payload) & 0xFFFFFFFF})
-        manifest = {"step": step, "entries": entries, "extra": extra or {},
-                    "format": 1}
-        _write_atomic(os.path.join(stage, MANIFEST),
-                      json.dumps(manifest, indent=1).encode())
-        os.rename(stage, ckpt_dir)          # commit
-        self._last_save_t = time.monotonic()
-        self._gc()
+        if threading.current_thread() is not self._thread:
+            self._last_save_t = time.monotonic()  # save_async stamps its own
+        with TraceAnnotation("ckpt.write"):
+            ckpt_dir = os.path.join(self.directory, f"step_{step:010d}")
+            stage = ckpt_dir + ".staging"
+            os.makedirs(stage, exist_ok=True)
+            entries = []
+            for name, leaf in _tree_paths(tree):
+                with TraceAnnotation("ckpt.serialize"):
+                    arr = np.asarray(jax.device_get(leaf))
+                    payload = _npy_bytes(arr)
+                    crc = zlib.crc32(payload) & 0xFFFFFFFF
+                fname = name.replace("/", ".") + ".npy"
+                _write_atomic(os.path.join(stage, fname), payload)
+                entries.append({"name": name, "file": fname,
+                                "shape": list(arr.shape),
+                                "dtype": str(arr.dtype), "crc32": crc})
+            with TraceAnnotation("ckpt.commit"):
+                manifest = {"step": step, "entries": entries,
+                            "extra": extra or {}, "format": 1}
+                _write_atomic(os.path.join(stage, MANIFEST),
+                              json.dumps(manifest, indent=1).encode())
+                os.rename(stage, ckpt_dir)          # commit
+                self._gc()
         return ckpt_dir
 
     def save_async(self, step: int, tree,
                    extra: Optional[dict] = None) -> bool:
         """Snapshot to host now; write on a background thread.  Returns
-        False when the writer throttle skipped this save (a more recent
-        save is close enough behind us)."""
-        self.wait()                          # one in flight at a time
-        if self.min_interval_s > 0 and self._last_save_t is not None \
-                and time.monotonic() - self._last_save_t < self.min_interval_s:
+        False, at once and without waiting on the writer, when the
+        throttle skipped this save (the last save was called too
+        recently; one still being written always is)."""
+        now = time.monotonic()
+        if self.min_interval_s > 0 and self._recent(now):
             self.throttle_skipped += 1
             return False
-        host_tree = jax.tree.map(lambda x: np.asarray(jax.device_get(x)),
-                                 tree)
+        self.wait()                          # one in flight at a time
+        self._last_save_t = now
+        with TraceAnnotation("ckpt.snapshot"):
+            host_tree = jax.tree.map(
+                lambda x: np.asarray(jax.device_get(x)), tree)
 
         def work():
             try:
@@ -117,9 +134,15 @@ class CheckpointManager:
         self._thread.start()
         return True
 
+    def _recent(self, now: float) -> bool:
+        writing = self._thread is not None and self._thread.is_alive()
+        return writing or (self._last_save_t is not None
+                           and now - self._last_save_t < self.min_interval_s)
+
     def wait(self) -> None:
         if self._thread is not None:
-            self._thread.join()
+            with TraceAnnotation("ckpt.wait_writer"):
+                self._thread.join()
             self._thread = None
         if self._error is not None:
             err, self._error = self._error, None

@@ -68,8 +68,9 @@ def make_train_step(cfg: ModelConfig, ocfg: OptimizerConfig,
 
         if compression is not None:
             grads = compression.roundtrip(grads)
-        params, opt_state, stats = apply_updates(ocfg, params, grads,
-                                                 opt_state)
+        with jax.named_scope("optimizer"):
+            params, opt_state, stats = apply_updates(ocfg, params, grads,
+                                                     opt_state)
         metrics = dict(metrics)
         metrics.update(stats)
         return params, opt_state, metrics

@@ -21,6 +21,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.session import StepCallback
 from repro.models import init_params
+from repro.obs.metrics import MetricsRegistry
 from repro.train.checkpoint import CheckpointManager
 from repro.train.optimizer import OptimizerConfig, for_model, init_opt_state
 from repro.train.train_step import make_train_step
@@ -77,6 +78,11 @@ class Trainer:
             donate_argnums=(0, 1))
         self.metrics_log: list = []
         self.final_state = None            # (params, opt_state) after run()
+        # train.starved_dispatches: steps dispatched after the previous
+        # step had already finished, so the device sat idle waiting on
+        # the host (read without blocking, through ``is_ready``)
+        self.telemetry = MetricsRegistry()
+        self._starved = self.telemetry.counter("train.starved_dispatches")
         # Profiling goes through the repro.profiler façade: pass a
         # Profiler (or ProfilerOptions) with a step_window, or use the
         # legacy TrainerConfig.profile_first/last fields, which build an
@@ -160,36 +166,51 @@ class Trainer:
                 "rank_report": rank_report,
                 "profile_reports": (self.profiler.reports
                                     if self.profiler else []),
+                "telemetry": self.telemetry.snapshot(),
                 # unified repro.profiler.Report views of the same windows
                 "reports": (self.profiler_facade.reports
                             if self.profiler_facade is not None else [])}
 
     def _run_span(self, params, opt_state, step) -> int:
+        """The step loop.  Each step is a ``StepTraceAnnotation`` and its
+        host phases ``train.*`` spans, so a profiler trace lines the host
+        up with the device work of the same step."""
+        metrics = None
         while step < self.tcfg.steps:
-            if self.profiler:
-                self.profiler.on_step_begin(step)
-            batch_tokens = next(self.batches)
-            batch = {"tokens": jnp.asarray(batch_tokens)}
-            batch.update(self.extra_batch)
-            if self.failure:
-                self.failure.maybe_fail(step)
-            params, opt_state, metrics = self._step_fn(params, opt_state,
-                                                       batch)
-            if self.profiler:
-                self.profiler.on_step_end(step)
-            step += 1
-            if step % self.tcfg.log_every == 0 or step == self.tcfg.steps:
-                m = {k: float(v) for k, v in metrics.items()}
-                m["step"] = step
-                self.metrics_log.append(m)
-            if step % self.tcfg.checkpoint_every == 0 \
-                    or step == self.tcfg.steps:
-                tree = {"params": params, "opt": opt_state}
-                if self.tcfg.checkpoint_async and step != self.tcfg.steps:
-                    self.ckpt.save_async(step, tree, extra={"step": step})
-                else:
-                    self.ckpt.wait()     # drain any in-flight async save
-                    self.ckpt.save(step, tree, extra={"step": step})
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                if self.profiler:
+                    self.profiler.on_step_begin(step)
+                with jax.profiler.TraceAnnotation("train.input"):
+                    batch_tokens = next(self.batches)
+                with jax.profiler.TraceAnnotation("train.to_device"):
+                    batch = {"tokens": jnp.asarray(batch_tokens)}
+                batch.update(self.extra_batch)
+                if self.failure:
+                    self.failure.maybe_fail(step)
+                if metrics is not None and metrics["loss"].is_ready():
+                    self._starved.inc()
+                with jax.profiler.TraceAnnotation("train.dispatch"):
+                    params, opt_state, metrics = self._step_fn(
+                        params, opt_state, batch)
+                if self.profiler:
+                    self.profiler.on_step_end(step)
+                step += 1
+                if step % self.tcfg.log_every == 0 or step == self.tcfg.steps:
+                    m = {k: float(v) for k, v in metrics.items()}
+                    m["step"] = step
+                    self.metrics_log.append(m)
+                if step % self.tcfg.checkpoint_every == 0 \
+                        or step == self.tcfg.steps:
+                    with jax.profiler.TraceAnnotation("train.save"):
+                        self._save(step, params, opt_state)
         # keep final state reachable for callers/tests
         self.final_state = (params, opt_state)
         return step
+
+    def _save(self, step: int, params, opt_state) -> None:
+        tree = {"params": params, "opt": opt_state}
+        if self.tcfg.checkpoint_async and step != self.tcfg.steps:
+            self.ckpt.save_async(step, tree, extra={"step": step})
+        else:
+            self.ckpt.wait()     # drain any in-flight async save
+            self.ckpt.save(step, tree, extra={"step": step})

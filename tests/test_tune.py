@@ -4,6 +4,7 @@ migration, thread resize via PipelineControl, checkpoint throttle),
 registry integration, options validation, and the local closed loop
 through the Profiler façade."""
 import os
+import time
 
 import pytest
 
@@ -305,6 +306,51 @@ class TestCheckpointThrottle:
         assert ckpt.throttle_skipped == 1
         ckpt.set_throttle(0.0)
         assert ckpt.save_async(3, tree)        # throttle off again
+        ckpt.wait()
+        assert ckpt.latest_step() == 3
+
+    def test_skipped_save_does_not_wait_for_the_writer(self, tmp_path,
+                                                       monkeypatch):
+        import threading
+        from repro.train import checkpoint as ckpt_mod
+        release = threading.Event()
+        write = ckpt_mod._write_atomic
+
+        def held_write(path, data):
+            release.wait(30)
+            write(path, data)
+
+        monkeypatch.setattr(ckpt_mod, "_write_atomic", held_write)
+        ckpt = ckpt_mod.CheckpointManager(str(tmp_path / "ck"), keep=10)
+        tree = {"w": __import__("numpy").zeros((4,))}
+        ckpt.set_throttle(60.0)
+        try:
+            assert ckpt.save_async(1, tree)
+            t = time.monotonic()
+            assert not ckpt.save_async(2, tree)    # in flight: recent
+            assert time.monotonic() - t < 1.0
+            assert ckpt._thread.is_alive()         # it did not join
+            assert ckpt.throttle_skipped == 1
+        finally:
+            release.set()
+        ckpt.wait()
+        assert ckpt.latest_step() == 1
+
+    def test_throttle_counts_from_the_last_call(self, tmp_path, monkeypatch):
+        from repro.train import checkpoint as ckpt_mod
+        from types import SimpleNamespace
+        clock = [100.0]
+        monkeypatch.setattr(ckpt_mod, "time",
+                            SimpleNamespace(monotonic=lambda: clock[0]))
+        ckpt = ckpt_mod.CheckpointManager(str(tmp_path / "ck"), keep=10)
+        tree = {"w": __import__("numpy").zeros((4,))}
+        ckpt.set_throttle(10.0)
+        assert ckpt.save_async(1, tree)
+        ckpt.wait()
+        clock[0] = 109.0                           # 9 s after the call
+        assert not ckpt.save_async(2, tree)
+        clock[0] = 110.0
+        assert ckpt.save_async(3, tree)
         ckpt.wait()
         assert ckpt.latest_step() == 3
 
