@@ -14,9 +14,13 @@ call:
   three steps);
 * the window: from the moment the last set-up step's outputs are ready,
   for ``seconds``; the feed then refuses the next batch (``WindowClosed``),
-  which also keeps the loop off its final synchronous save.  The window
-  ends when the last dispatched step's outputs are ready, or when the
-  last save started in it has committed, whichever is later.
+  which also keeps the loop off its final synchronous save.  Where the
+  mix caps its saves (``saves_in_window``), the feed also refuses the
+  batch of a step after which the program would start one save more
+  than that, however early, so a faster step never adds a second save's
+  stall and commit to the window.  The window ends when the last
+  dispatched step's outputs are ready, or when the last save started in
+  it has committed, whichever is later.
 
 Steps are timed from output-ready to output-ready by a waiter thread
 that blocks on each step's metrics in order, so the loop itself never
@@ -24,6 +28,13 @@ syncs.  The harness records its spans around each call into a layer
 (``next(batches)``, the step dispatch, the profiler hooks, the saves) as
 ``jax.profiler.TraceAnnotation``s too, so a traced run can name what the
 host was doing in each device idle gap.
+
+A traced run also reads the program's own tracing (``harness/progtrace``):
+its host spans name the idle gaps too, and the step's device time is split
+by the step's named scopes (from the step's optimized HLO, taken after the
+window from the compile cache).  Every run counts the program's
+``train.starved_dispatches`` (``Trainer.telemetry``) over the window's
+steps.
 """
 from __future__ import annotations
 
@@ -41,7 +52,7 @@ import jax
 import numpy as np
 
 from harness import check as check_lib
-from harness import devtrace, spec as spec_lib, traffic as traffic_lib
+from harness import devtrace, progtrace, spec as spec_lib, traffic as traffic_lib
 
 JOIN_S = 600.0
 COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
@@ -106,6 +117,7 @@ class Waiter(threading.Thread):
         self.ready: List[float] = []
         self.first: Dict[int, tuple] = {}
         self.t0: Optional[float] = None
+        self.lead = 0             # steps dispatched ahead as the window opens
         self.error: Optional[BaseException] = None
 
     def run(self):
@@ -120,6 +132,7 @@ class Waiter(threading.Thread):
                 if step == self.warmup - 1:
                     with jax.profiler.TraceAnnotation(devtrace.WINDOW_MARK):
                         self.t0 = t
+                    self.lead = self.q.qsize()
                 self.ready.append(t)
                 if step < check_lib.REF_STEPS:
                     self.first[step] = (float(metrics["loss"]),
@@ -132,12 +145,17 @@ class Waiter(threading.Thread):
 class Feed:
     """Wraps the program's batch iterator: times each ``next``, notes the
     crc of each batch, starts the trace before the window, and closes
-    the window."""
+    the window: at ``seconds`` after it opened, or at the batch of a step
+    after which the program (saving after every ``save_every``-th step)
+    would start a save beyond the first ``saves_in_window`` of the
+    window's steps."""
 
     def __init__(self, it, waiter: Waiter, spans: Spans, seconds: float,
-                 trace_dir: Optional[str]):
+                 trace_dir: Optional[str], save_every: int = 0,
+                 saves_in_window: Optional[int] = None):
         self.it, self.waiter, self.spans = it, waiter, spans
         self.seconds, self.trace_dir = seconds, trace_dir
+        self.save_every, self.saves_in_window = save_every, saves_in_window
         self.crcs: List[int] = []
 
     def __iter__(self):
@@ -148,6 +166,8 @@ class Feed:
         t0 = self.waiter.t0
         if t0 is not None and time.perf_counter() >= t0 + self.seconds:
             raise WindowClosed
+        if self.saves_full(i):
+            raise WindowClosed
         if self.trace_dir and i == self.waiter.warmup - 1:
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
@@ -156,6 +176,14 @@ class Feed:
             batch = next(self.it)
         self.crcs.append(traffic_lib.batch_crc(batch))
         return batch
+
+    def saves_full(self, step: int) -> bool:
+        """Whether dispatching ``step`` would start a save past the cap."""
+        if self.saves_in_window is None or (step + 1) % self.save_every:
+            return False
+        started = sum(s.step >= self.waiter.warmup
+                      for s in self.spans.of("save_stall"))
+        return started >= self.saves_in_window
 
 
 @dataclass
@@ -174,6 +202,13 @@ class Run:
     saves: List[dict]
     profiled: bool
     trace: Optional[devtrace.Summary] = None
+    # the program's own tracing, in traced runs: its host spans on the
+    # host clock, the window's device time by scope and the step's HLO;
+    # and the starved dispatches among the window's steps
+    program_spans: List[progtrace.HostSpan] = field(default_factory=list)
+    scope_unions: Optional[progtrace.Unions] = None
+    hlo: Optional[progtrace.Hlo] = None
+    starved: Optional[int] = None
 
     @property
     def window_steps(self) -> range:
@@ -192,6 +227,28 @@ class Run:
         """When the state that a save at ``step`` holds was ready: the
         outputs of the step before it."""
         return self.ready[step - 1]
+
+    def scope_ms(self, scopes=None) -> Optional[float]:
+        """Device milliseconds per window step in which an op of the
+        step's program under one of ``scopes`` ran (any op where None)."""
+        if self.scope_unions is None:
+            return None
+        return (1e3 * progtrace.device_s(self.scope_unions, scopes)
+                / len(self.window_steps))
+
+    def per_save_s(self, name: str) -> Optional[float]:
+        """Seconds per window save in the program's span ``name``: its
+        spans whose midpoint lies in the step thread's or the writer's
+        time of a save that the window's steps started (the harness's
+        ``save_stall`` and ``save_write`` spans)."""
+        saves = [s for s in self.spans.items if s.step >= self.warmup
+                 and s.name in ("save_stall", "save_write")]
+        inside = [p.t1 - p.t0 for p in self.program_spans if p.name == name
+                  and any(w.t0 <= (p.t0 + p.t1) / 2 <= w.t1 for w in saves)]
+        writes = sum(s.name == "save_write" for s in saves)
+        if not inside or not writes:
+            return None
+        return sum(inside) / writes
 
 
 @dataclass
@@ -248,7 +305,8 @@ def run_cell(cell: spec_lib.Cell, seed: int, seconds: float, trace: bool,
     trace_dir = os.path.join(workdir, "trace") if trace else None
     spans, waiter = Spans(), Waiter(warmup)
     feed = Feed(tokens_mod.token_batches(shards, batch, seq, cfg.vocab_size),
-                waiter, spans, seconds, trace_dir)
+                waiter, spans, seconds, trace_dir, tcfg.checkpoint_every,
+                tr.get("saves_in_window"))
     trainer = trainer_mod.Trainer(cfg, tcfg, feed, ocfg=ocfg)
     key = seed_key(seed)
     reads = _instrument(trainer, waiter, spans, ref, model, key)
@@ -283,13 +341,17 @@ def run_cell(cell: spec_lib.Cell, seed: int, seconds: float, trace: bool,
             jax.monitoring.unregister_event_duration_listener(on_compile)
     if waiter.error is not None:
         raise waiter.error
+    starved = (_starved(trainer) - reads["starved_at_open"]
+               if "starved_at_open" in reads else None)
     commits = [s.t1 for s in spans.of("save_write")]
     t_end = max([waiter.ready[-1]] + commits)
     cb = trainer.profiler
     if cb is not None and cb.session._active:
         cb.session.stop()
     if trace_dir:
+        t = time.perf_counter()
         jax.profiler.stop_trace()
+        log(f"trace stopped in {time.perf_counter() - t:.3f} s")
     dev = jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()),
@@ -297,22 +359,23 @@ def run_cell(cell: spec_lib.Cell, seed: int, seconds: float, trace: bool,
                   "peak_bytes_in_use", 0))}
     n_steps = len(waiter.ready)
     in_window = sum(t >= waiter.t0 for t in compiles)
+    times = np.diff(waiter.ready[warmup - 1:])
     log(f"window: steps {warmup}..{n_steps - 1}, "
         f"{waiter.ready[-1] - waiter.t0!r} s to the last step, "
         f"{t_end - waiter.t0!r} s in all; {in_window} compiles in it; "
-        f"saves at steps {[r['step'] for r in reads['saves']]}")
+        f"saves at steps {[r['step'] for r in reads['saves']]}; "
+        f"{waiter.lead} steps queued as it opened; longest step "
+        f"{times.max():.3f} s, step {warmup + int(times.argmax())}")
 
     run = Run(cell=cell.name, tokens_per_step=batch * seq, warmup=warmup,
               t_start=t_start, t0=waiter.t0, t_end=t_end,
               ready=list(waiter.ready),
               flops_per_step=ref.flops_per_step(model, batch, seq),
               peak_flops=spec_lib.peaks(dev.device_kind)["bf16_flops"],
-              spans=spans, saves=reads["saves"], profiled=cb is not None)
+              spans=spans, saves=reads["saves"], profiled=cb is not None,
+              starved=starved)
     if trace_dir:
-        events = devtrace.load(devtrace.find_xplane(trace_dir))
-        run.trace = devtrace.reduce(
-            events, run.t0, run.t_end,
-            [(s.name, s.t0, s.t1) for s in spans.items])
+        _read_trace(run, devtrace.find_xplane(trace_dir), reads)
         if run.trace is not None:
             device.update(busy_s=run.trace.busy_s, window_s=run.trace.window_s)
 
@@ -321,18 +384,55 @@ def run_cell(cell: spec_lib.Cell, seed: int, seconds: float, trace: bool,
                    device=device, window_compiles=in_window)
 
 
+def _read_trace(run: Run, path: str, reads: dict) -> None:
+    """Puts the traced window on ``run``: the device's busy time and its
+    gaps, named by the harness's and the program's spans, and the step's
+    device time by scope, from the step's optimized HLO."""
+    t = time.perf_counter()
+    trace = progtrace.read(path)
+    t_read = time.perf_counter()
+    run.program_spans = progtrace.program_spans(trace, run.t0)
+    run.trace = devtrace.reduce(
+        trace.ops if trace.on_device else [], trace.mark, run.t0, run.t_end,
+        [(s.name, s.t0, s.t1) for s in run.spans.items]
+        + [(s.name, s.t0, s.t1) for s in run.program_spans])
+    t_reduce = time.perf_counter()
+    text = reads["step_fn"].lower(*reads["shapes"]).compile().as_text()
+    run.hlo = progtrace.parse_hlo(text)
+    t_hlo = time.perf_counter()
+    run.scope_unions = progtrace.scope_unions(trace, run.t0, run.t_end, run.hlo)
+    log(f"trace: {len(trace.ops)} ops read in {t_read - t:.3f} s, reduced "
+        f"in {t_reduce - t_read:.3f} s; step HLO {run.hlo.module} in "
+        f"{t_hlo - t_reduce:.3f} s; split by scope in "
+        f"{time.perf_counter() - t_hlo:.3f} s")
+
+
+def _starved(trainer) -> int:
+    return trainer.telemetry.snapshot()["counters"].get(
+        "train.starved_dispatches", 0)
+
+
 def _instrument(trainer, waiter: Waiter, spans: Spans, ref, model: dict, key):
     """Wraps the step, the profiler hooks, the saves and the writer's file
     writes.  Returns the dict the check reads from: the optimizer's first
     moment after step one (on the host), the change norms after step
-    three, each save's checksums and the bytes the writer wrote."""
-    reads: Dict[str, object] = {"saves": []}
+    three, each save's checksums and the bytes the writer wrote; and,
+    for the trace, the jitted step with its first call's argument shapes
+    and the starvation counter at the last set-up step's dispatch."""
+    reads: Dict[str, object] = {"saves": [], "step_fn": trainer._step_fn}
     step_fn = trainer._step_fn
     change = check_lib.change_norms(ref, model)
     calls = [0]
 
     def step(params, opt_state, batch):
         i = calls[0]
+        if i == 0:
+            reads["shapes"] = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                               sharding=x.sharding),
+                (params, opt_state, batch))
+        if i == waiter.warmup - 1:
+            reads["starved_at_open"] = _starved(trainer)
         with spans("dispatch", i):
             out = step_fn(params, opt_state, batch)
         calls[0] += 1

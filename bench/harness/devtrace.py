@@ -1,6 +1,6 @@
-"""Reduction of a profiler trace (``.xplane.pb``) to the device's busy
-and idle time over the measured window, per-step device time, and the
-``breakdown`` of the result line.
+"""Reduction of a profiler trace's device ops (``progtrace.read``) to the
+device's busy and idle time over the measured window, per-step device
+time, and the ``breakdown`` of the result line.
 
 Device operations are the events on the ``XLA Ops`` line of each
 ``/device:`` plane.  The harness's own spans are kept on the host's
@@ -12,21 +12,12 @@ from __future__ import annotations
 import glob
 import os
 from dataclasses import dataclass, field
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 WINDOW_MARK = "bench.window_start"
 OPS_LINE = "XLA Ops"
 TOP = 10
 CONTROL_FLOW = (" while(", " conditional(", " call(")
-
-
-@dataclass
-class Event:
-    plane: str
-    line: str
-    name: str
-    start_s: float
-    dur_s: float
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -35,25 +26,6 @@ def find_xplane(trace_dir: str) -> str:
     if not found:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
     return found[-1]
-
-
-def load(path: str) -> List[Event]:
-    """Device op events and the window mark, on the trace's clock."""
-    from jax.profiler import ProfileData
-    data = ProfileData.from_file(path)
-    out = []
-    for plane in data.planes:
-        device = plane.name.startswith("/device:")
-        for line in plane.lines:
-            if device and line.name == OPS_LINE:
-                out.extend(Event(plane.name, line.name, e.name,
-                                 e.start_ns * 1e-9, e.duration_ns * 1e-9)
-                           for e in line.events)
-            elif not device:
-                out.extend(Event(plane.name, line.name, e.name,
-                                 e.start_ns * 1e-9, e.duration_ns * 1e-9)
-                           for e in line.events if e.name == WINDOW_MARK)
-    return out
 
 
 def union(intervals: Iterable[Tuple[float, float]]) -> List[Tuple[float, float]]:
@@ -76,52 +48,51 @@ class Summary:
     devices: int = 1
 
 
-def reduce(events: List[Event], t0: float, t_end: float,
+def reduce(ops: Sequence, mark: Optional[float], t0: float, t_end: float,
            spans: List[Tuple[str, float, float]]) -> Optional[Summary]:
-    """``t0``/``t_end`` and ``spans`` (name, start, end) are on the
-    host's ``perf_counter`` clock, and ``t0`` is the instant the window
-    mark was written.  Returns None where the trace holds no device op
-    or no mark."""
-    marks = [e.start_s for e in events if e.name == WINDOW_MARK]
-    ops = [e for e in events if e.line == OPS_LINE]
-    if not marks or not ops:
+    """``ops`` are the device's ops (``progtrace.Op``: plane, instruction
+    name, start and duration on the trace clock, and whether it holds
+    others), ``mark`` the window mark's instant on the trace clock.
+    ``t0``/``t_end`` and ``spans`` (name, start, end) are on the host's
+    ``perf_counter`` clock, and ``t0`` is the instant the window mark was
+    written.  Returns None where there is no device op or no mark."""
+    if mark is None or not ops:
         return None
-    shift = marks[0] - t0                         # trace clock - host clock
+    shift = mark - t0                             # trace clock - host clock
     lo, hi = t0 + shift, t_end + shift
     window = t_end - t0
-    planes = sorted({e.plane for e in ops})
-    busy_total = 0.0
+    by_plane: Dict[str, List[Tuple[float, float]]] = {}
     per_op: Dict[str, float] = {}
-    gaps: List[Tuple[float, float]] = []
-    for plane in planes:
-        iv = []
-        for e in ops:
-            if e.plane != plane:
-                continue
-            a, b = max(e.start_s, lo), min(e.start_s + e.dur_s, hi)
-            if b > a:
-                iv.append((a, b))
+    for e in ops:
+        iv = by_plane.setdefault(e.plane, [])
+        a, b = max(e.start_s, lo), min(e.start_s + e.dur_s, hi)
+        if b > a:
+            iv.append((a, b))
+            if not e.control:
                 per_op[e.name] = per_op.get(e.name, 0.0) + (b - a)
+    busy_total = 0.0
+    gaps: List[Tuple[float, float]] = []
+    for iv in by_plane.values():
         busy = union(iv)
         busy_total += sum(b - a for a, b in busy)
         edges = [lo] + [x for ab in busy for x in ab] + [hi]
         gaps += [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
                  if edges[i + 1] > edges[i]]
-    top_ops = sorted(((short_name(k), v) for k, v in per_op.items()
-                      if not any(c in k for c in CONTROL_FLOW)),
-                     key=lambda kv: -kv[1])[:TOP]
+    n = len(by_plane)
+    top_ops = sorted(per_op.items(), key=lambda kv: -kv[1])[:TOP]
     top_gaps = sorted(gaps, key=lambda g: g[0] - g[1])[:TOP]
     named = [[host_activity(spans, (a + b) / 2 - shift), b - a]
              for a, b in top_gaps]
-    return Summary(busy_s=busy_total / len(planes), window_s=window,
-                   device_ops=[[k, v / len(planes)] for k, v in top_ops],
-                   idle_gaps=named, devices=len(planes))
+    return Summary(busy_s=busy_total / n, window_s=window,
+                   device_ops=[[k, v / n] for k, v in top_ops],
+                   idle_gaps=named, devices=n)
 
 
 def short_name(op: str) -> str:
     """``%fusion.12 = bf16[...] fusion(...), ...`` -> ``fusion.12``.  Ops
     that hold others (while, conditional, call) are left out of the top
-    list, whose entries would otherwise count their bodies twice."""
+    list (``reduce``), whose entries would otherwise count their bodies
+    twice."""
     return op.split(" = ", 1)[0].lstrip("%")
 
 

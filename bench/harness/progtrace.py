@@ -1,4 +1,6 @@
-"""The program's own tracing, read off a profiler trace (``.xplane.pb``).
+"""The program's own tracing, read off a profiler trace (``.xplane.pb``),
+with the device ops that ``devtrace.reduce`` reduces: the harness reads
+each trace once, here.
 
 * Host spans: the step loop's ``jax.profiler.StepTraceAnnotation``
   (``train``) and ``TraceAnnotation``s named ``train.*``, ``profiler.*``
@@ -14,8 +16,8 @@
 
 An op is keyed by its HLO module as well as its instruction name: other
 programs run in the window (the save's checksum, the snapshot's
-transfers) and reuse names such as ``fusion.12``; their ops count as
-``other``.  Device ops are the ``XLA Ops`` events of each ``/device:``
+transfers) and reuse names such as ``fusion.12``; their ops fall under
+no scope.  Device ops are the ``XLA Ops`` events of each ``/device:``
 plane, whose module is the ``XLA Modules`` event around them, else the
 event's ``hlo_module`` stat; with no device plane (the CPU backend) they
 are the host events that carry ``hlo_op`` and ``hlo_module`` stats.
@@ -72,6 +74,7 @@ class Trace:
     ops: List[Op]
     spans: List[HostSpan]         # the program's spans, on the trace clock
     mark: Optional[float]         # the window mark's instant, trace clock
+    on_device: bool = True        # ops of device planes, not host events
 
 
 def read(path: str) -> Trace:
@@ -101,7 +104,8 @@ def read(path: str) -> Trace:
                             plane.name, str(st["hlo_op"]),
                             str(st.get("hlo_module")), e.start_ns * 1e-9,
                             e.duration_ns * 1e-9, False))
-    return Trace(ops=ops or host_ops, spans=spans, mark=mark)
+    return Trace(ops=ops or host_ops, spans=spans, mark=mark,
+                 on_device=bool(ops))
 
 
 def _device_ops(plane) -> List[Op]:
@@ -245,48 +249,47 @@ def _by_dataflow(instrs: Dict[str, _Instr]) -> None:
                     i.scope, changed = found, True
 
 
-def scope_times(trace: Trace, t0: float, t_end: float, hlo: Hlo,
-                groups: Optional[Dict[str, Sequence[str]]] = None,
-                ) -> Optional[Dict[str, float]]:
-    """Seconds of the window [t0, t_end] (host clock, ``t0`` the window
-    mark's instant, as for ``devtrace.reduce``) in which a device op of
-    each group of scopes ran: the union of those ops' intervals, averaged
-    over devices.  ``groups`` defaults to one group per scope.  Only ops
-    of ``hlo``'s module count under a scope; an op whose module the trace
-    does not give is taken to be of it.  ``other`` is the busy time
-    outside every group's union, so the groups and ``other`` sum to the
-    busy time where groups do not overlap in time.  Ops that hold others
-    (while, conditional, call) count only through the ops they run.
-    None where the trace holds no device op or mark."""
+Unions = Dict[str, Dict[Optional[str], List[Tuple[float, float]]]]
+
+
+def scope_unions(trace: Trace, t0: float, t_end: float,
+                 hlo: Hlo) -> Optional[Unions]:
+    """Per device plane and scope, the union of the intervals in which
+    the device ops of the window [t0, t_end] ran (host clock, ``t0`` the
+    window mark's instant, as for ``devtrace.reduce``), on the trace
+    clock.  Only ops of ``hlo``'s module take a scope; an op whose module
+    the trace does not give is taken to be of it.  Ops that hold others
+    (while, conditional, call) count only through the ops they run, and
+    fall, with every op of no scope, under None.  None where the trace
+    holds no device op or mark."""
     if trace.mark is None or not trace.ops:
         return None
-    groups = groups or {s: (s,) for s in SCOPES}
     shift = trace.mark - t0
     lo, hi = t0 + shift, t_end + shift
-    member = {s: g for g, ss in groups.items() for s in ss}
-    planes = sorted({op.plane for op in trace.ops})
-    total = dict.fromkeys(list(groups) + ["other"], 0.0)
-    for plane in planes:
-        busy, by_group = [], {g: [] for g in groups}
-        for op in trace.ops:
-            if op.plane != plane:
-                continue
-            a, b = max(op.start_s, lo), min(op.start_s + op.dur_s, hi)
-            if b <= a:
-                continue
-            busy.append((a, b))
-            if (op.module not in (None, hlo.module) or op.control
-                    or op.name in hlo.control):
-                continue
-            g = member.get(hlo.scopes.get(op.name))
-            if g is not None:
-                by_group[g].append((a, b))
-        for g, iv in by_group.items():
-            total[g] += _length(devtrace.union(iv))
-        scoped = _length(devtrace.union(x for iv in by_group.values()
-                                        for x in iv))
-        total["other"] += _length(devtrace.union(busy)) - scoped
-    return {g: v / len(planes) for g, v in total.items()}
+    planes: Unions = {op.plane: {} for op in trace.ops}
+    for op in trace.ops:
+        a, b = max(op.start_s, lo), min(op.start_s + op.dur_s, hi)
+        if b <= a:
+            continue
+        scope = None
+        if not (op.module not in (None, hlo.module) or op.control
+                or op.name in hlo.control):
+            scope = hlo.scopes.get(op.name)
+        planes[op.plane].setdefault(scope, []).append((a, b))
+    return {p: {s: devtrace.union(iv) for s, iv in by.items()}
+            for p, by in planes.items()}
+
+
+def device_s(planes: Unions, scopes: Optional[Iterable[str]] = None) -> float:
+    """Seconds in which a device op under one of ``scopes`` ran (any op
+    where None), from ``scope_unions``, averaged over devices."""
+    wanted = None if scopes is None else frozenset(scopes)
+    total = 0.0
+    for by_scope in planes.values():
+        total += _length(devtrace.union(
+            x for s, iv in by_scope.items()
+            if wanted is None or s in wanted for x in iv))
+    return total / len(planes)
 
 
 def _length(intervals: Iterable[Tuple[float, float]]) -> float:

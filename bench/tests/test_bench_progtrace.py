@@ -8,7 +8,6 @@ import pytest
 
 import tiny
 from harness import devtrace, progtrace
-from harness.devtrace import Event
 
 
 @pytest.mark.parametrize("op_name, scope", [
@@ -88,12 +87,17 @@ def _op(name, a, b, module="jit_step"):
                         name.startswith("while"))
 
 
-def _events(ops):
-    """The same ops as ``devtrace.load`` gives them, for its busy time."""
-    return [Event("/host:CPU", "w", devtrace.WINDOW_MARK, 101.0, 0.0)] + [
-        Event(o.plane, devtrace.OPS_LINE,
-              f"%{o.name} = f32[8]{{0}} {o.name.split('.')[0]}(...)",
-              o.start_s, o.dur_s) for o in ops]
+def _split(trace, hlo, groups=None, t0=1.0, t_end=2.0):
+    """Seconds of the window under each group of scopes, and ``other``,
+    the busy time outside them all."""
+    unions = progtrace.scope_unions(trace, t0, t_end, hlo)
+    if unions is None:
+        return None
+    groups = groups or {s: (s,) for s in progtrace.SCOPES}
+    out = {g: progtrace.device_s(unions, ss) for g, ss in groups.items()}
+    out["other"] = progtrace.device_s(unions) - progtrace.device_s(
+        unions, [s for ss in groups.values() for s in ss])
+    return out
 
 
 def test_scope_times_split_the_busy_time():
@@ -119,16 +123,16 @@ def test_scope_times_split_the_busy_time():
     trace = progtrace.Trace(ops, [], 101.0)
     groups = {"ssd": ("ssd",), "proj": ("in_proj", "out_proj"),
               "loss": ("loss",), "optimizer": ("optimizer",)}
-    t = progtrace.scope_times(trace, 1.0, 2.0, hlo, groups)
+    t = _split(trace, hlo, groups)
     assert t["ssd"] == pytest.approx(0.25)
     assert t["proj"] == pytest.approx(0.2)
     assert t["loss"] == pytest.approx(0.1)
     assert t["optimizer"] == pytest.approx(0.1)
-    busy = devtrace.reduce(_events(ops), 1.0, 2.0, []).busy_s
+    busy = devtrace.reduce(ops, 101.0, 1.0, 2.0, []).busy_s
     assert busy == pytest.approx(0.9)
     assert t["other"] == pytest.approx(0.25)
     assert sum(t.values()) == pytest.approx(busy)
-    each = progtrace.scope_times(trace, 1.0, 2.0, hlo)
+    each = _split(trace, hlo)
     assert set(each) == set(progtrace.SCOPES) | {"other"}
     assert each["in_proj"] == pytest.approx(0.1)
     assert each["conv"] == 0.0
@@ -141,8 +145,7 @@ def test_ops_of_another_module_count_as_other():
     step = [_op("dot.1", 101.0, 101.2), _op("fusion.2", 101.2, 101.3)]
     other = [_op("dot.1", 101.5, 101.6, "jit_checksums"),
              _op("fusion.2", 101.6, 101.8, "jit_checksums")]
-    t = progtrace.scope_times(progtrace.Trace(step + other, [], 101.0),
-                              1.0, 2.0, hlo)
+    t = _split(progtrace.Trace(step + other, [], 101.0), hlo)
     assert t["in_proj"] == pytest.approx(0.2)
     assert t["ssd"] == pytest.approx(0.1)
     assert t["other"] == pytest.approx(0.3)
@@ -150,16 +153,16 @@ def test_ops_of_another_module_count_as_other():
     # with no module in the trace, names alone decide
     bare = [progtrace.Op(o.plane, o.name, None, o.start_s, o.dur_s, False)
             for o in step + other]
-    t = progtrace.scope_times(progtrace.Trace(bare, [], 101.0), 1.0, 2.0, hlo)
+    t = _split(progtrace.Trace(bare, [], 101.0), hlo)
     assert t["in_proj"] == pytest.approx(0.3)
     assert t["other"] == pytest.approx(0.0)
 
 
 def test_no_device_ops_gives_nothing():
     hlo = progtrace.parse_hlo(HLO)
-    assert progtrace.scope_times(progtrace.Trace([], [], 0.0), 0.0, 1.0,
-                                 hlo) is None
-    assert progtrace.scope_times(
+    assert progtrace.scope_unions(progtrace.Trace([], [], 0.0), 0.0, 1.0,
+                                  hlo) is None
+    assert progtrace.scope_unions(
         progtrace.Trace([_op("dot.1", 0.0, 0.5)], [], None), 0.0, 1.0,
         hlo) is None
 
