@@ -23,7 +23,7 @@ def _time(fn, *args, reps=3):
 def run(rows: Row) -> None:
     import jax
     import jax.numpy as jnp
-    from repro.kernels import ops, ref
+    from repro.kernels import ref
     from repro.kernels.flash_attention import flash_attention_pallas
     from repro.models.flash import flash_attention_xla
 
@@ -57,11 +57,14 @@ def run(rows: Row) -> None:
     A = -jnp.exp(jnp.zeros(nh))
     Dp = jnp.ones(nh)
 
-    from repro.models.ssm import ssd_chunked
+    from repro.kernels.ssd_scan import ssd_pallas
+    from repro.models.ssm import reference_scan, ssd_chunked
     xla_ssd = jax.jit(lambda *a: ssd_chunked(*a, chunk=128))
     us = _time(xla_ssd, x, Bm, Cm, dt, A, Dp)
-    y_pal, h_pal = ops.ssd(x, Bm, Cm, dt, A, Dp, chunk=128)
-    y_ref, h_ref = ref.ssd_ref(x, Bm, Cm, dt, A, Dp)
+    xbc = jnp.concatenate([x.reshape(b, S, nh * P), Bm, Cm], axis=-1)
+    y_pal, h_pal = ssd_pallas(jnp.swapaxes(xbc, 1, 2), dt, A, Dp, 128,
+                              state=N)
+    y_ref, h_ref = reference_scan(x, Bm, Cm, dt, A, Dp)
     err = float(jnp.max(jnp.abs(y_pal - y_ref)))
     rows.add(f"ssd_chunked_xla_{S}", us, f"pallas_vs_ref_err={err:.2e}")
 

@@ -1,6 +1,7 @@
 """Pallas TPU kernels for the framework's compute hot spots (the paper
-itself is an I/O paper — see DESIGN.md §2): flash attention and the
-Mamba2 SSD chunk scan, each with a pure-jnp oracle in ref.py."""
+itself is an I/O paper — see DESIGN.md §2): flash attention, with its
+pure-jnp oracle in ref.py, and the Mamba2 SSD scan (ssd_scan.py), whose
+oracles are repro.models.ssm's ssd_chunked and reference_scan."""
 from repro.kernels import ops, ref
 
 __all__ = ["ops", "ref"]

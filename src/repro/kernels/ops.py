@@ -1,7 +1,7 @@
-"""Jit'd public wrappers around the Pallas kernels.
+"""Jit'd public wrapper around the flash-attention Pallas kernel.
 
 The kernels run compiled on a TPU and in the Pallas interpreter on any
-other backend (``repro.kernels.platform``).  The wrappers adapt the
+other backend (``repro.kernels.platform``).  The wrapper adapts the
 model-side (B, S, H, D) layout to the kernels' (B, H, S, D) TPU-friendly
 layout.
 """
@@ -12,7 +12,6 @@ from functools import partial
 import jax
 
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.ssd_scan import ssd_scan as _ssd_scan
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "softcap"))
@@ -25,9 +24,3 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
         v.transpose(0, 2, 1, 3), causal=causal, window=win,
         softcap=softcap)
     return out.transpose(0, 2, 1, 3)
-
-
-@partial(jax.jit, static_argnames=("chunk",))
-def ssd(x, B, C, dt, A, D, chunk: int = 256):
-    """Mamba2 SSD over full sequences (see kernels/ssd_scan.py)."""
-    return _ssd_scan(x, B, C, dt, A, D, chunk)

@@ -5,5 +5,9 @@ from __future__ import annotations
 import jax
 
 
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 def interpret_mode() -> bool:
-    return jax.default_backend() != "tpu"
+    return not on_tpu()

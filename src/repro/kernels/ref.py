@@ -28,23 +28,3 @@ def attention_ref(q, k, v, *, causal: bool = True, window: int = 0,
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p,
                       v.astype(jnp.float32)).astype(q.dtype)
-
-
-def ssd_ref(x, B, C, dt, A, D):
-    """Sequential SSD recurrence oracle.
-
-    x: (b, S, nh, P); B, C: (b, S, N); dt: (b, S, nh); A, D: (nh,).
-    Returns (y (b, S, nh, P) f32, h_final (b, nh, N, P) f32)."""
-    b, S, nh, P = x.shape
-    N = B.shape[-1]
-    h = jnp.zeros((b, nh, N, P), jnp.float32)
-    ys = []
-    xf = x.astype(jnp.float32)
-    for t in range(S):
-        a_t = jnp.exp(dt[:, t] * A[None, :])                    # (b, nh)
-        upd = jnp.einsum("bn,bhp,bh->bhnp", B[:, t], xf[:, t], dt[:, t])
-        h = h * a_t[..., None, None] + upd
-        y = jnp.einsum("bn,bhnp->bhp", C[:, t], h) \
-            + D[None, :, None] * xf[:, t]
-        ys.append(y)
-    return jnp.stack(ys, axis=1), h

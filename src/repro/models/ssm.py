@@ -4,13 +4,15 @@ Discrete recurrence per head (state N, head dim P):
     h_t = exp(dt_t * A) * h_{t-1} + dt_t * (B_t ⊗ x_t)      h: (N, P)
     y_t = C_t · h_t + D * x_t
 
-The training path uses the chunked SSD algorithm: quadratic attention-like
-compute inside length-L chunks plus a linear inter-chunk state recurrence.
-A step-by-step ``reference_scan`` (the oracle used in tests and by the
-Pallas kernel's ref) and a single-token ``decode_step`` are provided.
+Training and prefill use the chunked SSD algorithm: quadratic
+attention-like compute inside length-L chunks plus a linear inter-chunk
+state recurrence, through ``ssd``: the fused Pallas kernels on a TPU,
+else the XLA ``ssd_chunked``.  A step-by-step ``reference_scan`` (the
+tests' oracle) and a single-token ``mamba_decode_step`` are provided.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Optional
 
 import jax
@@ -19,6 +21,8 @@ import numpy as np
 from jax import lax
 
 from repro.configs.base import ModelConfig
+from repro.distributed.sharding import activation_axes
+from repro.kernels import platform, ssd_scan
 from repro.models.layers import dense_init, dtype_of, rms_norm, rms_norm_init
 
 
@@ -84,22 +88,26 @@ def causal_conv(xBC: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
 
 def _preprocess(p: dict, cfg: ModelConfig, x: jnp.ndarray,
                 conv_state: Optional[jnp.ndarray] = None):
-    """Shared front half: in_proj + conv + head split + dt/A."""
+    """Shared front half: in_proj + conv + dt/A."""
     cdt = dtype_of(cfg.compute_dtype)
-    d_inner, nh, P, N = dims(cfg)
     with jax.named_scope("in_proj"):
         zxbcdt = x.astype(cdt) @ p["in_proj"].astype(cdt)
     z, xBC, dt_raw = _split_proj(cfg, zxbcdt)
     xBC, new_conv = causal_conv(xBC, p["conv_w"].astype(cdt),
                                 p["conv_b"].astype(cdt), conv_state)
-    xs = xBC[..., :d_inner]
-    Bmat = xBC[..., d_inner:d_inner + N].astype(jnp.float32)
-    Cmat = xBC[..., d_inner + N:].astype(jnp.float32)
-    B_, S_ = x.shape[0], x.shape[1]
-    xh = xs.reshape(B_, S_, nh, P)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])  # (B,S,nh)
     A = -jnp.exp(p["A_log"])                                          # (nh,)
-    return z, xh, Bmat, Cmat, dt, A, new_conv
+    return z, xBC, dt, A, new_conv
+
+
+def split_xbc(xBC: jnp.ndarray, n_heads: int, state: int):
+    """The conv output [x | B | C] (B, S, nh*P + 2N) -> x as heads
+    (B, S, nh, P), and B, C (B, S, N) in f32."""
+    d_inner = xBC.shape[-1] - 2 * state
+    xh = xBC[..., :d_inner].reshape(
+        xBC.shape[:-1] + (n_heads, d_inner // n_heads))
+    return (xh, xBC[..., d_inner:d_inner + state].astype(jnp.float32),
+            xBC[..., d_inner + state:].astype(jnp.float32))
 
 
 @jax.named_scope("out_proj")
@@ -203,34 +211,78 @@ def reference_scan(xh, Bmat, Cmat, dt, A, D,
 
 
 def _intra_dtype(cfg: ModelConfig):
-    from repro.models.layers import dtype_of
     return dtype_of(cfg.ssm.intra_dtype)
 
 
-def mamba_forward(p: dict, cfg: ModelConfig, x: jnp.ndarray,
-                  impl: str = "chunked"):
+# Trace-time record of which path each ``ssd`` call took (True: the
+# Pallas kernel), for whoever is tracing inside ``ssd_paths()``.
+_PATH_LOGS: list = []
+
+
+@contextlib.contextmanager
+def ssd_paths():
+    """Collects, while open, one bool per ``ssd`` call traced: True
+    where the call took the fused kernel."""
+    taken: list = []
+    _PATH_LOGS.append(taken)
+    try:
+        yield taken
+    finally:
+        _PATH_LOGS.remove(taken)
+
+
+def ssd(xBC, dt, A, D, chunk: int, *, state: int, intra_dtype=jnp.float32,
+        out_dtype=None):
+    """The SSD scan of every Mamba2 block, from the conv output [x | B | C]
+    (B, S, nh*P + 2N), dt (B, S, nh) and A, D (nh,).  Takes the fused
+    Pallas kernel (``repro.kernels.ssd_scan``) on a TPU, in a program with
+    no sharding policy (the kernel is not partitioned), at shapes it
+    tiles; else ``ssd_chunked``.  Returns (y (B, S, nh, P) in
+    ``out_dtype``, default ``intra_dtype``; h_final (B, nh, N, P) f32)."""
+    _, S, nh = dt.shape
+    P = (xBC.shape[-1] - 2 * state) // nh
+    use_kernel = (platform.on_tpu() and activation_axes() is None
+                  and ssd_scan.tiles(S, nh, P, state, chunk))
+    for log in _PATH_LOGS:
+        log.append(use_kernel)
+    if use_kernel:
+        # XLA lays the conv's output out channels-major, as the kernel
+        # reads it, so this transpose is free; it lands in the conv's last
+        # fusion, whose work it is scoped with
+        with jax.named_scope("conv"):
+            xbc_t = jnp.swapaxes(xBC, 1, 2)
+        with jax.named_scope("ssd"):
+            return ssd_scan.ssd_pallas(xbc_t, dt, A, D, chunk, state=state,
+                                       intra_dtype=intra_dtype,
+                                       out_dtype=out_dtype)
+    y, h_final = ssd_chunked(*split_xbc(xBC, nh, state), dt, A, D, chunk,
+                             intra_dtype=intra_dtype)
+    return y.astype(out_dtype or y.dtype), h_final
+
+
+def mamba_forward(p: dict, cfg: ModelConfig, x: jnp.ndarray):
     """Full-sequence mamba block. x: (B, S, d) -> (B, S, d)."""
-    z, xh, Bmat, Cmat, dt, A, _ = _preprocess(p, cfg, x)
-    if impl == "chunked":
-        y, _ = ssd_chunked(xh, Bmat, Cmat, dt, A, p["D"], cfg.ssm.chunk_size,
-                           intra_dtype=_intra_dtype(cfg))
-    else:
-        y, _ = reference_scan(xh, Bmat, Cmat, dt, A, p["D"])
+    z, xBC, dt, A, _ = _preprocess(p, cfg, x)
+    y, _ = ssd(xBC, dt, A, p["D"], cfg.ssm.chunk_size,
+               state=cfg.ssm.state_dim, intra_dtype=_intra_dtype(cfg),
+               out_dtype=dtype_of(cfg.compute_dtype))
     return _finish(p, cfg, y, z)
 
 
 def mamba_prefill(p: dict, cfg: ModelConfig, x: jnp.ndarray):
     """Forward that also returns (conv_state, ssm_state) for decoding."""
-    z, xh, Bmat, Cmat, dt, A, conv_state = _preprocess(p, cfg, x)
-    y, h_final = ssd_chunked(xh, Bmat, Cmat, dt, A, p["D"], cfg.ssm.chunk_size,
-                             intra_dtype=_intra_dtype(cfg))
-    return _finish(p, cfg, y, z), (conv_state, h_final.astype(jnp.float32))
+    z, xBC, dt, A, conv_state = _preprocess(p, cfg, x)
+    y, h_final = ssd(xBC, dt, A, p["D"], cfg.ssm.chunk_size,
+                     state=cfg.ssm.state_dim, intra_dtype=_intra_dtype(cfg),
+                     out_dtype=dtype_of(cfg.compute_dtype))
+    return _finish(p, cfg, y, z), (conv_state, h_final)
 
 
 def mamba_decode_step(p: dict, cfg: ModelConfig, x: jnp.ndarray,
                       conv_state: jnp.ndarray, ssm_state: jnp.ndarray):
     """x: (B, 1, d); states from prefill.  Returns (y, new_conv, new_ssm)."""
-    z, xh, Bmat, Cmat, dt, A, new_conv = _preprocess(p, cfg, x, conv_state)
+    z, xBC, dt, A, new_conv = _preprocess(p, cfg, x, conv_state)
+    xh, Bmat, Cmat = split_xbc(xBC, dt.shape[-1], cfg.ssm.state_dim)
     x_t = xh[:, 0]                                       # (B,nh,P)
     b_t, c_t, dt_t = Bmat[:, 0], Cmat[:, 0], dt[:, 0]
     a_t = jnp.exp(dt_t * A[None, :])

@@ -10,6 +10,7 @@ steps, then let the advisor adjust reader parallelism / propose staging.
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Iterator, Optional
@@ -21,6 +22,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.session import StepCallback
 from repro.models import init_params
+from repro.models.ssm import ssd_paths
 from repro.obs.metrics import MetricsRegistry
 from repro.train.checkpoint import CheckpointManager
 from repro.train.optimizer import OptimizerConfig, for_model, init_opt_state
@@ -72,17 +74,20 @@ class Trainer:
         self.extra_batch = extra_batch or {}
         self.ckpt = CheckpointManager(tcfg.checkpoint_dir,
                                       keep=tcfg.keep_checkpoints)
-        self._step_fn = jax.jit(
-            make_train_step(cfg, self.ocfg,
-                            microbatches=tcfg.microbatches),
-            donate_argnums=(0, 1))
         self.metrics_log: list = []
         self.final_state = None            # (params, opt_state) after run()
         # train.starved_dispatches: steps dispatched after the previous
         # step had already finished, so the device sat idle waiting on
-        # the host (read without blocking, through ``is_ready``)
+        # the host (read without blocking, through ``is_ready``).
+        # model.ssd_kernel: 1 where the compiled step's SSD scans took
+        # the fused Pallas kernel, 0 where they fell back (set as the
+        # step is traced).
         self.telemetry = MetricsRegistry()
         self._starved = self.telemetry.counter("train.starved_dispatches")
+        self._step_fn = jax.jit(
+            self._record_ssd_path(make_train_step(
+                cfg, self.ocfg, microbatches=tcfg.microbatches)),
+            donate_argnums=(0, 1))
         # Profiling goes through the repro.profiler façade: pass a
         # Profiler (or ProfilerOptions) with a step_window, or use the
         # legacy TrainerConfig.profile_first/last fields, which build an
@@ -104,6 +109,17 @@ class Trainer:
         # shipping — reporter.ship / ship_socket — is the caller's call,
         # after run() returns).
         self.fleet_reporter = fleet_reporter
+
+    def _record_ssd_path(self, step):
+        gauge = self.telemetry.gauge("model.ssd_kernel")
+
+        @functools.wraps(step)
+        def train_step(params, opt_state, batch):
+            with ssd_paths() as taken:
+                out = step(params, opt_state, batch)
+            gauge.set(1.0 if taken and all(taken) else 0.0)
+            return out
+        return train_step
 
     def _make_facade(self, profiler):
         from repro.profiler import Profiler, ProfilerOptions

@@ -15,7 +15,6 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.ssd_scan import ssd_scan
 
 
 @pytest.fixture(scope="module")
@@ -65,13 +64,43 @@ def test_flash_attention_compiles_for_v5e(one_chip, H, KVH, D, S, window):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_ssd_scan_compiles_for_v5e_at_mamba2_370m(one_chip):
-    # mamba2-370m: 32 heads of head_dim 64, state 128, chunk 256
-    b, S, nh, P, N = 2, 512, 32, 64, 128
-    fn = partial(ssd_scan, chunk=256, interpret=False)
-    compiled = _compile(fn, one_chip,
-                        ((b, S, nh, P), jnp.bfloat16),
-                        ((b, S, N), jnp.float32), ((b, S, N), jnp.float32),
-                        ((b, S, nh), jnp.float32), ((nh,), jnp.float32),
-                        ((nh,), jnp.float32))
-    assert "tpu_custom_call" in compiled.as_text()
+@pytest.mark.parametrize("nh,N", [(32, 128), (64, 64)],
+                         ids=["mamba2-370m", "zamba2-1.2b"])
+def test_ssd_scan_compiles_for_v5e_at_mamba2_370m(one_chip, monkeypatch,
+                                                  nh, N):
+    """jax.grad of the model's SSD entry point at the configs' widths
+    (heads of 64, chunk 256; one chip's 4 x 2048 tokens) takes the fused
+    kernels, scoped ``ssd`` forward and backward, holds no (L, L, heads)
+    f32 tensor, and needs fewer temporary bytes than the XLA scan."""
+    import re
+
+    from repro.kernels import platform
+    from repro.models import ssm
+    b, S, P = 4, 2048, 64
+
+    def loss(*a):
+        y, h = ssm.ssd(*a, 256, state=N, out_dtype=jnp.bfloat16)
+        return jnp.sum(y.astype(jnp.float32)) + jnp.sum(h)
+
+    shapes = [((b, S, nh * P + 2 * N), jnp.bfloat16),
+              ((b, S, nh), jnp.float32), ((nh,), jnp.float32),
+              ((nh,), jnp.float32)]
+    temps = {}
+    for kernel in (True, False):
+        monkeypatch.setattr(platform, "on_tpu", lambda: kernel)
+        # a new function each time: jit caches what it traced
+        compiled = _compile(jax.grad(lambda *a: loss(*a), argnums=range(4)),
+                            one_chip, *shapes)
+        temps[kernel] = compiled.memory_analysis().temp_size_in_bytes
+        text = compiled.as_text()
+        calls = [ln for ln in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in ln]
+        if kernel:
+            assert len(calls) == 2                      # forward, backward
+            for ln in calls:
+                assert "ssd" in re.search(r'op_name="([^"]*)"', ln).group(1)
+            assert not re.search(rf"f32\[[0-9,]*256,256,{nh}\]", text)
+        else:
+            assert not calls
+            assert re.search(rf"f32\[[0-9,]*256,256,{nh}\]", text)
+    assert temps[True] < temps[False]
