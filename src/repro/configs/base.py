@@ -70,6 +70,7 @@ class ModelConfig:
     ssm: SSMConfig = field(default_factory=SSMConfig)
     # --- hybrid (zamba2): a shared attention block every k ssm blocks -------
     shared_attn_every: int = 0
+    adapter_rank: int = 0               # per-site LoRA rank of the shared MLP
     # --- encoder/decoder (whisper) ------------------------------------------
     n_encoder_layers: int = 0
     encoder_seq: int = 0                # precomputed frame count (stub frontend)
@@ -77,7 +78,7 @@ class ModelConfig:
     cross_attn_every: int = 0
     vision_tokens: int = 0              # precomputed patch-embedding count
     # --- misc ----------------------------------------------------------------
-    act: str = "silu"                   # silu | gelu
+    act: str = "silu"                   # silu | gelu (tanh) | gelu_erf (exact)
     mlp_gated: bool = True              # SwiGLU/GeGLU vs plain 2-matrix MLP
     norm_eps: float = 1e-5
     tie_embeddings: bool = False

@@ -91,6 +91,18 @@ def _trailing_spec(name: str, shape, cfg: ModelConfig, mesh: Mesh,
             return [None, t(ff), f(d)]
         ff, d = shape[-2:]                      # (ff, d)
         return [t(ff), f(d)]
+    if name == "w_gate_up":                     # (d, 2, ff) — zamba2 shared
+        d, _, ff = shape[-3:]
+        return [f(d), None, t(ff)]
+    if name == "adapter_a":                     # (sites, d, r)
+        d, r = shape[-2:]
+        return [f(d), None]
+    if name == "adapter_b":                     # (sites, r, 2, ff)
+        r, _, ff = shape[-3:]
+        return [None, None, t(ff)]
+    if name == "linear":                        # (sites, d, d)
+        d_in, d_out = shape[-2:]
+        return [f(d_in), None]
     if name == "router":                        # (d, E)
         d, E = shape[-2:]
         return [f(d), None]

@@ -6,7 +6,8 @@ from repro.models.model import (
     loss_fn,
     param_count,
     prefill,
+    shared_sites,
 )
 
 __all__ = ["decode_step", "forward", "init_cache", "init_params",
-           "loss_fn", "param_count", "prefill"]
+           "loss_fn", "param_count", "prefill", "shared_sites"]

@@ -19,6 +19,7 @@ keeping this function's static argnums hashable.
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -51,23 +52,25 @@ def _chunks(a, block):
     return a.reshape(B, S // block, block, H, D).transpose(1, 0, 2, 3, 4)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def flash_attention_xla(q, k, v, window, causal: bool = True,
                         block_k: int = 512, softcap: float = 0.0,
-                        q_offset: int = 0):
-    """q: (B,Sq,H,D); k,v: (B,Sk,KVH,D); window: f32 scalar (inf = none).
+                        q_offset: int = 0, scale: Optional[float] = None):
+    """q: (B,Sq,H,D); k,v: (B,Sk,KVH,D); window: f32 scalar (inf = none);
+    ``scale`` multiplies the f32 scores, 1/sqrt(D) where None.
 
     Returns (B,Sq,H,D) in q.dtype."""
     out, _ = _flash_fwd_impl(q, k, v, window, causal, block_k, softcap,
-                             q_offset)
+                             q_offset, scale)
     return out
 
 
-def _flash_fwd_impl(q, k, v, window, causal, block_k, softcap, q_offset):
+def _flash_fwd_impl(q, k, v, window, causal, block_k, softcap, q_offset,
+                    scale):
     B, Sq, H, D = q.shape
     Sk, KVH = k.shape[1], k.shape[2]
     G = H // KVH
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
     block_k = min(block_k, Sk)
     k_limit = Sk
     k, v = _pad_kv(k, v, block_k)
@@ -106,18 +109,18 @@ def _flash_fwd_impl(q, k, v, window, causal, block_k, softcap, q_offset):
     return out.astype(q.dtype), lse
 
 
-def _flash_fwd(q, k, v, window, causal, block_k, softcap, q_offset):
+def _flash_fwd(q, k, v, window, causal, block_k, softcap, q_offset, scale):
     out, lse = _flash_fwd_impl(q, k, v, window, causal, block_k, softcap,
-                               q_offset)
+                               q_offset, scale)
     return out, (q, k, v, window, out, lse)
 
 
-def _flash_bwd(causal, block_k, softcap, q_offset, res, do):
+def _flash_bwd(causal, block_k, softcap, q_offset, scale, res, do):
     q, k, v, window, out, lse = res
     B, Sq, H, D = q.shape
     Sk, KVH = k.shape[1], k.shape[2]
     G = H // KVH
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
     block_k = min(block_k, Sk)
     k_limit = Sk
     kp, vp = _pad_kv(k, v, block_k)

@@ -241,16 +241,18 @@ def blocked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 
 def naive_attention(q, k, v, *, causal: bool, window=None, softcap: float = 0.0,
-                    q_offset: int = 0, k_valid=None) -> jnp.ndarray:
+                    q_offset: int = 0, k_valid=None,
+                    scale: Optional[float] = None) -> jnp.ndarray:
     """Reference full-score attention.  Also the decode path (Sq == 1),
     where the score matrix is (B, H, 1, Sk) and therefore small.
 
     ``k_valid``: optional (B,) number of valid cache positions per sequence.
+    ``scale``: the scores' factor, 1/sqrt(D) by default.
     """
     B, Sq, H, D = q.shape
     _, Sk, KVH, _ = k.shape
     G = H // KVH
-    qg = q.reshape(B, Sq, KVH, G, D) * (D ** -0.5)
+    qg = q.reshape(B, Sq, KVH, G, D) * (D ** -0.5 if scale is None else scale)
     s = jnp.einsum("bqhgd,bkhd->bqhgk", qg, k,
                    preferred_element_type=jnp.float32)
     if softcap > 0.0:
@@ -270,13 +272,18 @@ def naive_attention(q, k, v, *, causal: bool, window=None, softcap: float = 0.0,
 
 
 def attention(cfg: ModelConfig, q, k, v, *, causal, window=None,
-              softcap: float = 0.0, q_offset: int = 0, k_valid=None):
-    """Dispatch on cfg.attention_impl and query length."""
+              softcap: float = 0.0, q_offset: int = 0, k_valid=None,
+              scale: Optional[float] = None):
+    """Dispatch on cfg.attention_impl and query length.  ``scale`` is the
+    scores' factor, 1/sqrt(head_dim) where None."""
     if q.shape[1] == 1 or cfg.attention_impl == "naive" or k_valid is not None:
         return naive_attention(q, k, v, causal=causal, window=window,
                                softcap=softcap, q_offset=q_offset,
-                               k_valid=k_valid)
+                               k_valid=k_valid, scale=scale)
     if cfg.attention_impl == "pallas":
+        if scale is not None:
+            raise ValueError("the Pallas attention kernel scales by "
+                             "1/sqrt(head_dim) only")
         from repro.kernels import ops as kops
         return kops.flash_attention(q, k, v, causal=causal, window=window,
                                     softcap=softcap)
@@ -285,7 +292,7 @@ def attention(cfg: ModelConfig, q, k, v, *, causal, window=None,
            else jnp.asarray(window, jnp.float32))
     return flash_attention_xla(
         q, k, v, win, causal, min(cfg.attention_block_k, k.shape[1]),
-        softcap, q_offset)
+        softcap, q_offset, scale)
 
 
 # ---------------------------------------------------------------------------
@@ -306,7 +313,10 @@ def mlp_init(key, cfg: ModelConfig, d_ff: Optional[int] = None) -> dict:
 
 
 def activation(name: str):
-    return jax.nn.silu if name == "silu" else partial(jax.nn.gelu, approximate=True)
+    """silu; gelu_erf, the exact GELU; else the tanh form of GELU."""
+    if name == "silu":
+        return jax.nn.silu
+    return partial(jax.nn.gelu, approximate=name != "gelu_erf")
 
 
 def mlp_apply(p: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:

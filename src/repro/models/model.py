@@ -61,6 +61,11 @@ def param_count(params) -> int:
     return sum(x.size for x in jax.tree.leaves(params))
 
 
+def shared_sites(cfg: ModelConfig) -> int:
+    """Applications of the hybrid family's shared block in one forward."""
+    return hybrid_lib.n_shared_sites(cfg) if cfg.family == "hybrid" else 0
+
+
 # ---------------------------------------------------------------------------
 # forward (train / prefill)
 

@@ -91,8 +91,8 @@ def layer_trips_for(arch: str) -> set:
         g = cfg.cross_attn_every - 1
         trips = {cfg.n_layers // cfg.cross_attn_every, g}
     elif cfg.family == "hybrid":
-        k = cfg.shared_attn_every
-        trips = {k, cfg.n_layers % k} - {0}
+        from repro.models.hybrid import scan_trips
+        trips = scan_trips(cfg)
     elif cfg.family == "encdec":
         trips = {cfg.n_layers, cfg.n_encoder_layers}
     return trips

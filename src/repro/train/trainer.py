@@ -21,7 +21,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.session import StepCallback
-from repro.models import init_params
+from repro.models import init_params, shared_sites
 from repro.models.ssm import ssd_paths
 from repro.obs.metrics import MetricsRegistry
 from repro.train.checkpoint import CheckpointManager
@@ -80,12 +80,13 @@ class Trainer:
         # step had already finished, so the device sat idle waiting on
         # the host (read without blocking, through ``is_ready``).
         # model.ssd_kernel: 1 where the compiled step's SSD scans took
-        # the fused Pallas kernel, 0 where they fell back (set as the
-        # step is traced).
+        # the fused Pallas kernel, 0 where they fell back; and
+        # model.shared_sites: the hybrid shared block's sites in the
+        # compiled step (both set as the step is traced).
         self.telemetry = MetricsRegistry()
         self._starved = self.telemetry.counter("train.starved_dispatches")
         self._step_fn = jax.jit(
-            self._record_ssd_path(make_train_step(
+            self._record_model_paths(make_train_step(
                 cfg, self.ocfg, microbatches=tcfg.microbatches)),
             donate_argnums=(0, 1))
         # Profiling goes through the repro.profiler façade: pass a
@@ -110,14 +111,16 @@ class Trainer:
         # after run() returns).
         self.fleet_reporter = fleet_reporter
 
-    def _record_ssd_path(self, step):
-        gauge = self.telemetry.gauge("model.ssd_kernel")
+    def _record_model_paths(self, step):
+        kernel = self.telemetry.gauge("model.ssd_kernel")
+        sites = self.telemetry.gauge("model.shared_sites")
 
         @functools.wraps(step)
         def train_step(params, opt_state, batch):
             with ssd_paths() as taken:
                 out = step(params, opt_state, batch)
-            gauge.set(1.0 if taken and all(taken) else 0.0)
+            kernel.set(1.0 if taken and all(taken) else 0.0)
+            sites.set(float(shared_sites(self.cfg)))
             return out
         return train_step
 

@@ -88,6 +88,27 @@ def test_cache_specs_fall_back_to_sequence_parallel():
     assert specs2["shared_k"][3] == "model"
 
 
+def test_zamba2_shared_block_shards_its_4096_wide_heads():
+    """The shared block reads [hidden | embedding], 4096 wide, into 32
+    heads of 128: q, k, v shard their heads over "model" and their input
+    over the batch axes; the per-site caches (sites, B, S, 32, 128) shard
+    their heads."""
+    from repro.configs import SHAPES_BY_NAME
+    from repro.models import init_cache
+    cfg = get_config("zamba2-1.2b")
+    pshape = params_struct(cfg)
+    specs = shd.param_specs(cfg, pshape, MESH)["stack"]
+    for name in ("wq", "wk", "wv"):
+        assert pshape["stack"]["shared"]["attn"][name].shape == (4096, 32, 128)
+        assert tuple(specs["shared"]["attn"][name]) == ("data", "model", None)
+    assert tuple(specs["shared"]["attn"]["wo"]) == ("model", None, "data")
+    assert tuple(specs["sites"]["adapter_b"]) == (None, None, None, "model")
+    cache = jax.eval_shape(lambda: init_cache(cfg, 2, 64))
+    assert cache["shared_k"].shape == (6, 2, 64, 32, 128)
+    kv = shd.cache_specs(cfg, SHAPES_BY_NAME["decode_32k"], MESH)["shared_k"]
+    assert len(kv) == 5 and kv[3] == "model"
+
+
 def test_shard_batch_noop_without_policy():
     x = jnp.ones((4, 8))
     assert shd.shard_batch(x) is x
@@ -126,6 +147,8 @@ MINI_DRYRUN = textwrap.dedent("""
     ("dbrx-132b", "train_4k"),
     ("mamba2-370m", "decode_32k"),
     ("whisper-tiny", "prefill_32k"),
+    ("zamba2-1.2b", "train_4k"),
+    ("zamba2-1.2b", "decode_32k"),
 ])
 def test_mini_dryrun_compiles_on_8_devices(arch, shape):
     code = MINI_DRYRUN.format(arch=arch, shape=shape)

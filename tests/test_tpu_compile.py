@@ -104,3 +104,56 @@ def test_ssd_scan_compiles_for_v5e_at_mamba2_370m(one_chip, monkeypatch,
             assert not calls
             assert re.search(rf"f32\[[0-9,]*256,256,{nh}\]", text)
     assert temps[True] < temps[False]
+
+
+def test_zamba2_train_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The benchmark's zamba2-1.2b train step (18 layers, 4 x 2048 tokens,
+    one microbatch) compiles for one v5e chip: the shared block's ops are
+    scoped ``shared_attn`` and ``shared_mlp``, the SSD takes the fused
+    kernel pair (every custom call scoped ``ssd``, forward and, under a
+    transpose, backward), and the arguments and temporaries
+    (14.1 GB) fit the chip's 16 GB with room for the runtime."""
+    import json
+    import re
+
+    from repro.kernels import platform
+    from repro.models import init_params
+    from repro.train.optimizer import OptimizerConfig, init_opt_state
+    from repro.train.train_step import make_train_step
+    from repro.configs.base import ModelConfig, SSMConfig
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench")
+    with open(os.path.join(bench, "configs", "zamba2-1.2b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(bench, "traffic", "steady.json")) as f:
+        traffic = json.load(f)
+    model = dict(config["model"], ssm=SSMConfig(**config["model"]["ssm"]))
+    cfg = ModelConfig(**model)
+    ocfg = OptimizerConfig(**config["optimizer"])
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
+
+    def state(key):
+        params = init_params(cfg, key)
+        return params, init_opt_state(ocfg, params)
+
+    params, opt = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(state, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((traffic["batch"], traffic["seq"]),
+                                  jnp.int32, sharding=one_chip)
+    compiled = jax.jit(make_train_step(cfg, ocfg),
+                       donate_argnums=(0, 1)).lower(
+        params, opt, {"tokens": tokens}).compile()
+    text = compiled.as_text()
+    for scope in ("shared_attn", "shared_mlp", "in_proj", "conv", "ssd",
+                  "out_proj"):
+        assert re.search(rf'op_name="[^"]*/{scope}/', text), scope
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    names = [re.search(r'op_name="([^"]*)"', ln).group(1) for ln in calls]
+    assert names and all(re.search(r"[/(]ssd[/)]", n) for n in names)
+    assert any("transpose(" in n for n in names)        # backward
+    assert any("transpose(" not in n for n in names)    # forward
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
