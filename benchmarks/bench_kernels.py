@@ -24,7 +24,7 @@ def run(rows: Row) -> None:
     import jax
     import jax.numpy as jnp
     from repro.kernels import ref
-    from repro.kernels.flash_attention import flash_attention_pallas
+    from repro.kernels.flash_attention import flash_attention
     from repro.models.flash import flash_attention_xla
 
     key = jax.random.PRNGKey(0)
@@ -38,12 +38,8 @@ def run(rows: Row) -> None:
     xla_fa = jax.jit(lambda q, k, v: flash_attention_xla(
         q, k, v, jnp.float32(jnp.inf), True, 128, 0.0, 0))
     us = _time(xla_fa, q, k, v)
-    o_pal = flash_attention_pallas(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), causal=True, block_q=128, block_k=128)
-    o_ref = ref.attention_ref(q.transpose(0, 2, 1, 3),
-                              k.transpose(0, 2, 1, 3),
-                              v.transpose(0, 2, 1, 3), causal=True)
+    o_pal = flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
+    o_ref = ref.attention_ref(q, k, v, causal=True)
     err = float(jnp.max(jnp.abs(o_pal - o_ref)))
     rows.add(f"flash_attention_xla_{Sq}", us,
              f"pallas_vs_ref_err={err:.2e}")

@@ -2,6 +2,8 @@
 interpreter on any other backend (the CPU test runs)."""
 from __future__ import annotations
 
+import contextlib
+
 import jax
 
 
@@ -11,3 +13,25 @@ def on_tpu() -> bool:
 
 def interpret_mode() -> bool:
     return not on_tpu()
+
+
+class PathLog:
+    """Trace-time record of which path each call of one dispatch took
+    (True: the Pallas kernel), for whoever traces inside ``collect()``."""
+
+    def __init__(self):
+        self._open: list = []
+
+    @contextlib.contextmanager
+    def collect(self):
+        """Collects, while open, one bool per call traced."""
+        taken: list = []
+        self._open.append(taken)
+        try:
+            yield taken
+        finally:
+            self._open.remove(taken)
+
+    def record(self, kernel: bool) -> None:
+        for taken in self._open:
+            taken.append(kernel)

@@ -8,10 +8,10 @@ Conventions
 * Compute runs in ``cfg.compute_dtype`` (bf16 by default); softmax, norm
   statistics and loss run in f32.
 * Attention is GQA-general: ``n_heads`` query heads grouped over
-  ``n_kv_heads`` KV heads.  The blocked implementation scans over KV chunks
-  with an online softmax so that no (S_q, S_k) score matrix is ever
-  materialized — this is the XLA-lowered analogue of the Pallas flash
-  kernel in ``repro.kernels`` and is what the multi-pod dry-run compiles.
+  ``n_kv_heads`` KV heads.  Full sequences take the fused Pallas pair
+  (``repro.kernels.flash_attention``) on a TPU, else the XLA blocked
+  online softmax (``repro.models.flash``), which the multi-pod dry-run
+  compiles; neither materializes the (S_q, S_k) scores.
 """
 from __future__ import annotations
 
@@ -24,6 +24,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ModelConfig
+from repro.distributed.sharding import activation_axes
+from repro.kernels import flash_attention as fa, platform
+from repro.models.flash import flash_attention_xla
 
 # ---------------------------------------------------------------------------
 # dtype helpers
@@ -179,67 +182,6 @@ def _chunk_bias(q_pos, k_pos, causal: bool, window) -> jnp.ndarray:
     return jnp.where(ok, 0.0, NEG_INF).astype(jnp.float32)
 
 
-def blocked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                      *, causal: bool, window=None,
-                      block_k: int = 512, softcap: float = 0.0,
-                      q_offset: int = 0) -> jnp.ndarray:
-    """GQA attention scanning over KV chunks with an online softmax.
-
-    q: (B, Sq, H, D); k, v: (B, Sk, KVH, D); returns (B, Sq, H, D).
-    ``window`` may be None, a python int, or a traced scalar (gemma3 selects
-    the window inside the layer scan).  ``q_offset`` shifts query positions
-    (used by decode paths that fall back to this implementation).
-    """
-    B, Sq, H, D = q.shape
-    _, Sk, KVH, _ = k.shape
-    G = H // KVH
-    scale = D ** -0.5
-
-    block_k = min(block_k, Sk)
-    if Sk % block_k:
-        # pad the KV sequence up to a chunk multiple; padded keys are
-        # masked out below via the k_pos < Sk check
-        pad = block_k - Sk % block_k
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    n_chunks = k.shape[1] // block_k
-    k_limit = Sk
-
-    qg = q.reshape(B, Sq, KVH, G, D) * scale
-    # scan carries: running max m, normalizer l, accumulator acc (f32)
-    m0 = jnp.full((B, Sq, KVH, G), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((B, Sq, KVH, G), jnp.float32)
-    acc0 = jnp.zeros((B, Sq, KVH, G, D), jnp.float32)
-
-    kc = k.reshape(B, n_chunks, block_k, KVH, D).transpose(1, 0, 2, 3, 4)
-    vc = v.reshape(B, n_chunks, block_k, KVH, D).transpose(1, 0, 2, 3, 4)
-    q_pos = q_offset + jnp.arange(Sq)
-
-    def body(carry, inputs):
-        m, l, acc, idx = carry
-        kb, vb = inputs  # (B, block_k, KVH, D)
-        s = jnp.einsum("bqhgd,bkhd->bqhgk", qg, kb,
-                       preferred_element_type=jnp.float32)
-        if softcap > 0.0:
-            s = jnp.tanh(s / softcap) * softcap
-        k_pos = idx * block_k + jnp.arange(block_k)
-        bias = _chunk_bias(q_pos, k_pos, causal, window)  # (Sq, block_k)
-        bias = bias + jnp.where(k_pos < k_limit, 0.0, NEG_INF)[None, :]
-        s = s + bias[None, :, None, None, :]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1)
-        acc_new = acc * alpha[..., None] + jnp.einsum(
-            "bqhgk,bkhd->bqhgd", p.astype(vb.dtype), vb,
-            preferred_element_type=jnp.float32)
-        return (m_new, l_new, acc_new, idx + 1), None
-
-    (m, l, acc, _), _ = lax.scan(body, (m0, l0, acc0, jnp.int32(0)), (kc, vc))
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.reshape(B, Sq, H, D).astype(q.dtype)
-
-
 def naive_attention(q, k, v, *, causal: bool, window=None, softcap: float = 0.0,
                     q_offset: int = 0, k_valid=None,
                     scale: Optional[float] = None) -> jnp.ndarray:
@@ -271,23 +213,51 @@ def naive_attention(q, k, v, *, causal: bool, window=None, softcap: float = 0.0,
     return o.reshape(B, Sq, H, D).astype(q.dtype)
 
 
+# ``attention_paths()`` collects one bool per full-sequence ``attention``
+# call traced while it is open: True where the call took the Pallas pair.
+_ATTENTION_PATHS = platform.PathLog()
+attention_paths = _ATTENTION_PATHS.collect
+
+
+def _takes_kernel(cfg: ModelConfig, q, k, window, q_offset) -> bool:
+    """The Pallas pair takes self-attention from position 0 with a static
+    window: under ``"pallas"`` always, under ``"blocked"`` on a TPU, in a
+    program with no sharding policy (the kernels are not partitioned), at
+    shapes it tiles."""
+    if not (isinstance(q_offset, int) and q_offset == 0
+            and (window is None or isinstance(window, int))
+            and q.shape[1] == k.shape[1]):
+        return False
+    if cfg.attention_impl == "pallas":
+        return True
+    return (cfg.attention_impl == "blocked" and platform.on_tpu()
+            and activation_axes() is None
+            and fa.tiles(q.shape[1], k.shape[1], q.shape[-1]))
+
+
 def attention(cfg: ModelConfig, q, k, v, *, causal, window=None,
               softcap: float = 0.0, q_offset: int = 0, k_valid=None,
               scale: Optional[float] = None):
-    """Dispatch on cfg.attention_impl and query length.  ``scale`` is the
-    scores' factor, 1/sqrt(head_dim) where None."""
-    if q.shape[1] == 1 or cfg.attention_impl == "naive" or k_valid is not None:
+    """Decode steps (one query) and cached prefill (``k_valid``) take
+    ``naive_attention``; full sequences the fused Pallas pair
+    (``repro.kernels.flash_attention``) where ``_takes_kernel``, else
+    ``flash_attention_xla`` (``"naive"``: ``naive_attention``).
+    ``scale`` is the scores' factor, 1/sqrt(head_dim) where None."""
+    if q.shape[1] == 1 or k_valid is not None:
         return naive_attention(q, k, v, causal=causal, window=window,
                                softcap=softcap, q_offset=q_offset,
                                k_valid=k_valid, scale=scale)
-    if cfg.attention_impl == "pallas":
-        if scale is not None:
-            raise ValueError("the Pallas attention kernel scales by "
-                             "1/sqrt(head_dim) only")
-        from repro.kernels import ops as kops
-        return kops.flash_attention(q, k, v, causal=causal, window=window,
-                                    softcap=softcap)
-    from repro.models.flash import flash_attention_xla
+    kernel = _takes_kernel(cfg, q, k, window, q_offset)
+    _ATTENTION_PATHS.record(kernel)
+    if kernel:
+        with jax.named_scope("attention"):
+            return fa.flash_attention(q, k, v, causal=causal,
+                                      window=window or 0, softcap=softcap,
+                                      scale=scale)
+    if cfg.attention_impl == "naive":
+        return naive_attention(q, k, v, causal=causal, window=window,
+                               softcap=softcap, q_offset=q_offset,
+                               scale=scale)
     win = (jnp.float32(jnp.inf) if window is None
            else jnp.asarray(window, jnp.float32))
     return flash_attention_xla(

@@ -12,7 +12,6 @@ tests' oracle) and a single-token ``mamba_decode_step`` are provided.
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Optional
 
 import jax
@@ -214,21 +213,10 @@ def _intra_dtype(cfg: ModelConfig):
     return dtype_of(cfg.ssm.intra_dtype)
 
 
-# Trace-time record of which path each ``ssd`` call took (True: the
-# Pallas kernel), for whoever is tracing inside ``ssd_paths()``.
-_PATH_LOGS: list = []
-
-
-@contextlib.contextmanager
-def ssd_paths():
-    """Collects, while open, one bool per ``ssd`` call traced: True
-    where the call took the fused kernel."""
-    taken: list = []
-    _PATH_LOGS.append(taken)
-    try:
-        yield taken
-    finally:
-        _PATH_LOGS.remove(taken)
+# ``ssd_paths()`` collects one bool per ``ssd`` call traced while it is
+# open: True where the call took the fused kernel.
+_SSD_PATHS = platform.PathLog()
+ssd_paths = _SSD_PATHS.collect
 
 
 def ssd(xBC, dt, A, D, chunk: int, *, state: int, intra_dtype=jnp.float32,
@@ -243,8 +231,7 @@ def ssd(xBC, dt, A, D, chunk: int, *, state: int, intra_dtype=jnp.float32,
     P = (xBC.shape[-1] - 2 * state) // nh
     use_kernel = (platform.on_tpu() and activation_axes() is None
                   and ssd_scan.tiles(S, nh, P, state, chunk))
-    for log in _PATH_LOGS:
-        log.append(use_kernel)
+    _SSD_PATHS.record(use_kernel)
     if use_kernel:
         # XLA lays the conv's output out channels-major, as the kernel
         # reads it, so this transpose is free; it lands in the conv's last

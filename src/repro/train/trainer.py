@@ -22,6 +22,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.session import StepCallback
 from repro.models import init_params, shared_sites
+from repro.models.layers import attention_paths
 from repro.models.ssm import ssd_paths
 from repro.obs.metrics import MetricsRegistry
 from repro.train.checkpoint import CheckpointManager
@@ -80,9 +81,11 @@ class Trainer:
         # step had already finished, so the device sat idle waiting on
         # the host (read without blocking, through ``is_ready``).
         # model.ssd_kernel: 1 where the compiled step's SSD scans took
-        # the fused Pallas kernel, 0 where they fell back; and
-        # model.shared_sites: the hybrid shared block's sites in the
-        # compiled step (both set as the step is traced).
+        # the fused Pallas kernel, 0 where they fell back;
+        # model.attention_kernel: the same for its full-sequence
+        # attention calls (0 where it has none); and model.shared_sites:
+        # the hybrid shared block's sites in the compiled step (all set
+        # as the step is traced).
         self.telemetry = MetricsRegistry()
         self._starved = self.telemetry.counter("train.starved_dispatches")
         self._step_fn = jax.jit(
@@ -112,14 +115,16 @@ class Trainer:
         self.fleet_reporter = fleet_reporter
 
     def _record_model_paths(self, step):
-        kernel = self.telemetry.gauge("model.ssd_kernel")
+        ssd = self.telemetry.gauge("model.ssd_kernel")
+        attn = self.telemetry.gauge("model.attention_kernel")
         sites = self.telemetry.gauge("model.shared_sites")
 
         @functools.wraps(step)
         def train_step(params, opt_state, batch):
-            with ssd_paths() as taken:
+            with ssd_paths() as ssd_taken, attention_paths() as attn_taken:
                 out = step(params, opt_state, batch)
-            kernel.set(1.0 if taken and all(taken) else 0.0)
+            for gauge, taken in ((ssd, ssd_taken), (attn, attn_taken)):
+                gauge.set(1.0 if taken and all(taken) else 0.0)
             sites.set(float(shared_sites(self.cfg)))
             return out
         return train_step

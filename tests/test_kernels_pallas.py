@@ -7,8 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import platform, ref, ssd_scan
-from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels import flash_attention as fa, platform, ref, ssd_scan
 from repro.models import ssm
 from repro.models.ssm import reference_scan, ssd_chunked
 
@@ -23,23 +22,86 @@ FLASH_CASES = [
 ]
 
 
+def _qkv(B, Sq, Sk, H, KVH, D, dtype, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (B, Sq, H, D)).astype(dtype)
+    k = jax.random.normal(ks[1], (B, Sk, KVH, D)).astype(dtype)
+    v = jax.random.normal(ks[2], (B, Sk, KVH, D)).astype(dtype)
+    return q, k, v
+
+
 @pytest.mark.parametrize(
     "B,Sq,Sk,H,KVH,D,causal,window,softcap,dtype", FLASH_CASES)
 def test_flash_attention_matches_oracle(B, Sq, Sk, H, KVH, D, causal,
                                         window, softcap, dtype):
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(ks[0], (B, H, Sq, D)).astype(dtype)
-    k = jax.random.normal(ks[1], (B, KVH, Sk, D)).astype(dtype)
-    v = jax.random.normal(ks[2], (B, KVH, Sk, D)).astype(dtype)
-    out = flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                 softcap=softcap, block_q=64, block_k=64,
-                                 interpret=True)
+    q, k, v = _qkv(B, Sq, Sk, H, KVH, D, dtype)
+    out = fa.flash_attention(q, k, v, causal=causal, window=window,
+                             softcap=softcap, block_q=64, block_k=64,
+                             interpret=True)
     expected = ref.attention_ref(q, k, v, causal=causal, window=window,
                                  softcap=softcap)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
                                 - expected.astype(jnp.float32))))
     assert err < tol, f"err={err}"
+
+
+FLASH_GRAD_CASES = [
+    # B, S, H, KVH, D, causal, scale, dtype; blocks of 64 over 256 steps:
+    # causal skips the 6 blocks above the diagonal and masks the 4 on it
+    (1, 256, 2, 2, 128, True, None, jnp.float32),
+    (2, 256, 2, 2, 128, True, 0.125, jnp.float32),     # zamba2's scale
+    (1, 256, 4, 1, 64, True, None, jnp.float32),       # GQA 4:1
+    (1, 256, 4, 2, 128, True, 0.125, jnp.bfloat16),    # GQA, bf16
+    (1, 192, 2, 2, 64, False, 0.3, jnp.bfloat16),      # no mask at all
+]
+
+
+@pytest.mark.parametrize("B,S,H,KVH,D,causal,scale,dtype", FLASH_GRAD_CASES)
+def test_flash_attention_grads_match_xla_and_naive(B, S, H, KVH, D, causal,
+                                                   scale, dtype):
+    """dq, dk and dv through the pair's custom VJP (interpret mode)
+    against ``flash_attention_xla``'s and ``naive_attention``'s."""
+    from repro.models.flash import flash_attention_xla
+    from repro.models.layers import naive_attention
+    q, k, v = _qkv(B, S, S, H, KVH, D, dtype, seed=7)
+    do = jax.random.normal(jax.random.PRNGKey(8), q.shape).astype(dtype)
+
+    def grads(fn):
+        return jax.vjp(fn, q, k, v)[1](do)
+
+    got = grads(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=causal, scale=scale, block_q=64, block_k=64,
+        interpret=True))
+    xla = grads(lambda q, k, v: flash_attention_xla(
+        q, k, v, jnp.float32(jnp.inf), causal, 64, 0.0, 0, scale))
+    naive = grads(lambda q, k, v: naive_attention(
+        q, k, v, causal=causal, scale=scale))
+    # f32: the same sums in another order; bf16: the operands' rounding
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    for name, g, x, n in zip(("dq", "dk", "dv"), got, xla, naive):
+        assert g.shape == n.shape and g.dtype == n.dtype, name
+        largest = float(jnp.max(jnp.abs(n.astype(jnp.float32))))
+        assert _max_err(g, x) <= tol * largest, name
+        assert _max_err(g, n) <= tol * largest, name
+
+
+@pytest.mark.parametrize("Sq,Sk,D,fits", [
+    (2048, 2048, 128, True),            # zamba2-1.2b
+    (4096, 4096, 256, True),            # gemma3-4b
+    (384, 384, 128, True),              # blocks of 128
+    (2048, 2048, 64, False),            # heads of half a lane tile
+    (100, 100, 128, False),             # no whole blocks
+    (1500, 1500, 64, False),            # whisper's encoder
+])
+def test_flash_attention_takes_shapes_that_tile(Sq, Sk, D, fits):
+    assert fa.tiles(Sq, Sk, D) is fits
+
+
+@pytest.mark.parametrize("S,block", [(2048, 512), (768, 256), (384, 128),
+                                     (100, 128)])
+def test_flash_attention_blocks_follow_the_sequence(S, block):
+    assert fa.block_size(S) == block
 
 
 def _ssd_inputs(b, S, nh, P, N, dtype, seed=1):
@@ -242,8 +304,72 @@ def test_ssd_fallback_is_ssd_chunked_and_the_trainer_reads_zero(tmp_path):
                                          log_every=100,
                                          checkpoint_dir=str(tmp_path)),
                       batches)
-    out = trainer.run()
-    assert out["telemetry"]["gauges"]["model.ssd_kernel"] == 0.0
+    gauges = trainer.run()["telemetry"]["gauges"]
+    assert gauges["model.ssd_kernel"] == 0.0
+    assert gauges["model.attention_kernel"] == 0.0       # no attention
+
+
+def _attention_cfg(impl="blocked"):
+    from repro.configs import get_config
+    return get_config("zamba2-1.2b", reduced=True).replace(
+        attention_impl=impl)
+
+
+@pytest.mark.parametrize("on_tpu,policy,S,D,impl,kw,kernel", [
+    (False, None, 256, 128, "blocked", {}, False),      # CPU: XLA path
+    (True, None, 256, 128, "blocked", {}, True),        # a TPU, one device
+    (True, ("data",), 256, 128, "blocked", {}, False),  # sharding policy
+    (True, None, 200, 128, "blocked", {}, False),       # no whole blocks
+    (True, None, 256, 64, "blocked", {}, False),        # half a lane tile
+    (True, None, 256, 128, "blocked", {"window": 64}, True),
+    (True, None, 256, 128, "blocked",                   # per-layer window
+     {"window": jnp.int32(64)}, False),
+    (True, None, 256, 128, "naive", {}, False),
+    (False, None, 200, 64, "pallas", {}, True),         # the pair, padded
+])
+def test_attention_path_follows_backend_sharding_and_shape(
+        monkeypatch, on_tpu, policy, S, D, impl, kw, kernel):
+    """What ``attention`` takes, seen by tracing it (nothing runs)."""
+    from repro.distributed import sharding
+    from repro.models import layers
+    monkeypatch.setattr(platform, "on_tpu", lambda: on_tpu)
+    monkeypatch.setattr(sharding, "_ACTIVATION_AXES", [policy])
+    q, k, v = _qkv(1, S, S, 2, 2, D, jnp.bfloat16)
+    with layers.attention_paths() as taken:
+        jax.eval_shape(lambda q, k, v: layers.attention(
+            _attention_cfg(impl), q, k, v, causal=True, scale=0.125, **kw),
+            q, k, v)
+    assert taken == [kernel]
+
+
+def test_attention_fallback_is_flash_xla_and_the_trainer_reads_zero(
+        tmp_path):
+    """On the CPU ``attention`` is ``flash_attention_xla``, value for
+    value, decode steps log no path, and a reduced zamba2 Trainer's
+    ``model.attention_kernel`` gauge reads 0 after its step traced."""
+    from repro.models import layers
+    from repro.models.flash import flash_attention_xla
+    from repro.train.trainer import Trainer, TrainerConfig
+    cfg = _attention_cfg()
+    q, k, v = _qkv(2, 256, 256, 4, 2, 128, jnp.bfloat16)
+    with layers.attention_paths() as taken:
+        out = layers.attention(cfg, q, k, v, causal=True, scale=0.125)
+        layers.attention(cfg, q[:, :1], k, v, causal=False, q_offset=255)
+    xla = flash_attention_xla(q, k, v, jnp.float32(jnp.inf), True,
+                              cfg.attention_block_k, 0.0, 0, 0.125)
+    assert bool(jnp.all(out == xla))
+    assert taken == [False]
+
+    rng = np.random.default_rng(0)
+    batches = (rng.integers(0, cfg.vocab_size, (2, 64)).astype(np.int32)
+               for _ in range(1))
+    trainer = Trainer(cfg, TrainerConfig(steps=1, checkpoint_every=100,
+                                         log_every=100,
+                                         checkpoint_dir=str(tmp_path)),
+                      batches)
+    gauges = trainer.run()["telemetry"]["gauges"]
+    assert gauges["model.attention_kernel"] == 0.0
+    assert gauges["model.shared_sites"] == 2.0
 
 
 def test_flash_xla_custom_vjp_grads_match_naive():

@@ -11,10 +11,11 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.flash_attention import flash_attention
 
 
 @pytest.fixture(scope="module")
@@ -54,14 +55,58 @@ def _compile(fn, one_chip, *shapes):
 @pytest.mark.parametrize("H,KVH,D,S,window", [
     (28, 4, 128, 4096, 0),      # qwen2-7b: GQA 7:1, full causal
     (8, 4, 256, 4096, 1024),    # gemma3-4b: head_dim 256, local window
-], ids=["qwen2-7b", "gemma3-4b"])
+    (32, 32, 128, 2048, 0),     # zamba2-1.2b: the shared attention
+], ids=["qwen2-7b", "gemma3-4b", "zamba2-1.2b"])
 def test_flash_attention_compiles_for_v5e(one_chip, H, KVH, D, S, window):
-    fn = partial(flash_attention_pallas, causal=True, window=window,
+    fn = partial(flash_attention, causal=True, window=window,
                  interpret=False)
-    compiled = _compile(fn, one_chip, ((1, H, S, D), jnp.bfloat16),
-                        ((1, KVH, S, D), jnp.bfloat16),
-                        ((1, KVH, S, D), jnp.bfloat16))
+    compiled = _compile(fn, one_chip, ((1, S, H, D), jnp.bfloat16),
+                        ((1, S, KVH, D), jnp.bfloat16),
+                        ((1, S, KVH, D), jnp.bfloat16))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_attention_grad_compiles_for_v5e_at_zamba2(one_chip, monkeypatch):
+    """jax.grad of the model's attention at zamba2-1.2b's shared block
+    (4 x 2048 tokens, 32 heads of 128, bf16, scale 0.125) takes the fused
+    pair, forward, dk/dv and dq, scoped ``attention``; holds no
+    (4, 2048, 32, 512) f32 score block; and needs fewer temporary bytes
+    than ``flash_attention_xla``."""
+    import re
+
+    from repro.configs import get_config
+    from repro.kernels import platform
+    from repro.models import layers
+    cfg = get_config("zamba2-1.2b")
+    B, S, H, D = 4, 2048, 32, 128
+    score_block = B * S * H * 512
+
+    def loss(q, k, v):
+        o = layers.attention(cfg, q, k, v, causal=True, scale=0.125)
+        return jnp.sum(o.astype(jnp.float32))
+
+    temps = {}
+    for kernel in (True, False):
+        monkeypatch.setattr(platform, "on_tpu", lambda: kernel)
+        # a new function each time: jit caches what it traced
+        compiled = _compile(jax.grad(lambda *a: loss(*a), argnums=(0, 1, 2)),
+                            one_chip, *[((B, S, H, D), jnp.bfloat16)] * 3)
+        temps[kernel] = compiled.memory_analysis().temp_size_in_bytes
+        text = compiled.as_text()
+        calls = [ln for ln in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in ln]
+        f32 = [int(np.prod([int(n) for n in dims.split(",")]))
+               for dims in re.findall(r"f32\[([0-9,]+)\]", text)]
+        if kernel:
+            assert len(calls) == 3              # forward, dk/dv, dq
+            for ln in calls:
+                name = re.search(r'op_name="([^"]*)"', ln).group(1)
+                assert "attention" in name
+            assert max(f32) < score_block
+        else:
+            assert not calls
+            assert max(f32) >= score_block
+    assert temps[True] < temps[False]
 
 
 @pytest.mark.parametrize("nh,N", [(32, 128), (64, 64)],
@@ -109,10 +154,11 @@ def test_ssd_scan_compiles_for_v5e_at_mamba2_370m(one_chip, monkeypatch,
 def test_zamba2_train_step_compiles_for_v5e(one_chip, monkeypatch):
     """The benchmark's zamba2-1.2b train step (18 layers, 4 x 2048 tokens,
     one microbatch) compiles for one v5e chip: the shared block's ops are
-    scoped ``shared_attn`` and ``shared_mlp``, the SSD takes the fused
-    kernel pair (every custom call scoped ``ssd``, forward and, under a
-    transpose, backward), and the arguments and temporaries
-    (14.1 GB) fit the chip's 16 GB with room for the runtime."""
+    scoped ``shared_attn`` and ``shared_mlp``, the SSD and the shared
+    attention take their fused kernel pairs (every custom call scoped
+    ``ssd``, or ``attention`` inside ``shared_attn``; forward and, under a
+    transpose, backward), and the arguments and temporaries fit the
+    chip's 16 GB with room for the runtime."""
     import json
     import re
 
@@ -152,8 +198,11 @@ def test_zamba2_train_step_compiles_for_v5e(one_chip, monkeypatch):
     calls = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     names = [re.search(r'op_name="([^"]*)"', ln).group(1) for ln in calls]
-    assert names and all(re.search(r"[/(]ssd[/)]", n) for n in names)
-    assert any("transpose(" in n for n in names)        # backward
-    assert any("transpose(" not in n for n in names)    # forward
+    ssd = [n for n in names if re.search(r"[/(]ssd[/)]", n)]
+    attn = [n for n in names if re.search(r"shared_attn\)?/attention/", n)]
+    assert ssd and attn and len(ssd) + len(attn) == len(names)
+    for kernel in (ssd, attn):
+        assert any("transpose(" in n for n in kernel)       # backward
+        assert any("transpose(" not in n for n in kernel)   # forward
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
